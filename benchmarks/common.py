@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import BaseImage
+from repro.launch.serve import finetune
 from repro.models import lm
 from repro.serve.engine import ServerlessNode, layerwise_state
 
@@ -92,20 +93,7 @@ def build_zoo(force: bool = False, **node_kwargs) -> ServerlessNode:
             )
         # "fine-tune": perturb the top ~40% of the stack + output head, so
         # the shared fraction lands in the paper's 17-51% ballpark (Fig 5)
-        params = dict(params)
-        params["pattern"] = list(params["pattern"])
-        params["final_norm"] = params["final_norm"] + 0.01 * (i + 1)
-        if "unembed" in params["embed"]:
-            params["embed"]["unembed"] = params["embed"]["unembed"] * (1.0 + 0.01 * (i + 1))
-        for pi in range(len(cfg.pattern)):
-            def bump(a, _pi=pi):
-                a = np.asarray(a)
-                if a.ndim >= 1 and a.shape[0] == cfg.pattern_reps:
-                    cut = int(cfg.pattern_reps * 0.6)
-                    a = a.copy()
-                    a[cut:] = a[cut:] * (1.0 + 0.02 * (i + 1))
-                return a
-            params["pattern"][pi] = jax.tree.map(bump, params["pattern"][pi])
+        params = finetune(cfg, params, 0.02 * (i + 1))
         jif = BENCH_DIR / f"{fname}.jif"
         # v1 images predate the ws boundary: republish so the working-set
         # promotion path (and residual extra state) is exercised

@@ -36,6 +36,8 @@ import time
 import traceback
 from pathlib import Path
 
+from repro.launch.compile_cache import enable_compile_cache
+
 REPO_ROOT = Path(__file__).resolve().parents[1]
 
 MODULES = [
@@ -82,6 +84,7 @@ def main() -> None:
     ap.add_argument("--only", default=None, help="comma-separated module list")
     args = ap.parse_args()
     mods = args.only.split(",") if args.only else MODULES
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = 0

@@ -66,6 +66,8 @@ class RestoreStats:
     upload_s: float = 0.0             # time spent in host->device transfers
     uploaded_bytes: int = 0           # bytes that actually crossed to HBM
     patched_on_device_bytes: int = 0  # tensor bytes materialized by the kernel
+    fused_tensors: int = 0        # planned to patch on device (private pages only)
+    full_upload_tensors: int = 0  # planned to assemble on host and upload whole
     # content-addressed dedup: bytes served per tier instead of pulled from
     # the image store, plus the metadata-time plan partition (chunk counts)
     chunk_resident_bytes: int = 0  # served from the RAM chunk cache (zero I/O)
@@ -376,6 +378,7 @@ class SpiceRestorer:
                     preloaded_region.release()
                 r.close()
                 raise
+            stats.add(fused_tensors=len(plans), full_upload_tensors=len(full_upload))
 
         # ---- dedup planning: partition chunk lists by digest -------------
         # Metadata-time only (the itables and digest regions are already
@@ -788,12 +791,14 @@ class SpiceRestorer:
         """Split this image's tensors between the two device-path modes:
         ``plans`` (name -> FusedPlan: upload private pages only, patch on
         device) and ``full_upload`` (host-assemble as usual, whole-tensor
-        upload off the reader thread).  Fusion applies when the page size
-        divides the dtype and the itable has BASE/ZERO pages to save; BASE
-        pages additionally need the device-resident base — a cache miss
-        under memory pressure falls back to full upload, never fails."""
+        upload off the reader thread).  Fusion applies when the page
+        views as whole kernel tiles of the dtype and the itable has
+        BASE/ZERO pages to save; BASE pages additionally need the
+        device-resident base — a cache miss under memory pressure falls
+        back to full upload, never fails."""
         # imported here: the host-only restore path must not pull in jax
         from repro.core.upload import FusedPlan
+        from repro.kernels.overlay_patch.kernel import tiles
         from repro.kernels.overlay_patch.ops import compact_plan_from_itable
 
         dp = self.device_path
@@ -807,7 +812,7 @@ class SpiceRestorer:
             it = r.itable(t.name)
             kinds, src, runs, n_priv = compact_plan_from_itable(it)
             n_pages = it.n_pages
-            if ps % dtype.itemsize != 0 or n_pages == 0 or n_priv == n_pages:
+            if not tiles(ps, dtype) or n_pages == 0 or n_priv == n_pages:
                 full.add(t.name)  # nothing to fuse (or pages unviewable)
                 continue
             page_elems = ps // dtype.itemsize

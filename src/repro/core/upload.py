@@ -75,7 +75,7 @@ class FusedPlan:
     kinds: np.ndarray
     src: np.ndarray
     runs: List[Tuple[int, int, int]]  # (compact_slot, data_chunk, count)
-    base_pages: Optional[object] = None  # device (n_pages, page_elems) or None
+    base_pages: Optional[object] = None  # device (n_pages, rows, LANES) or None
 
     @property
     def priv_bytes(self) -> int:
@@ -203,10 +203,12 @@ class UploadStream:
             import jax
             import jax.numpy as jnp
 
+            from repro.kernels.overlay_patch.kernel import page_shape
             from repro.kernels.overlay_patch.ops import overlay_patch_device
 
             try:
                 dtype = np.dtype(plan.dtype)
+                page = page_shape(plan.page_elems)
                 t0 = time.perf_counter()
                 if self.simulate_bw:
                     # only the private pages cross the interconnect
@@ -215,14 +217,14 @@ class UploadStream:
                     priv_host = (
                         buf[: plan.priv_bytes]
                         .view(dtype)
-                        .reshape(plan.n_priv, plan.page_elems)
+                        .reshape(plan.n_priv, *page)
                     )
                     priv = self.install(priv_host)
                 else:
-                    priv = jnp.zeros((1, plan.page_elems), dtype)
+                    priv = jnp.zeros((1, *page), dtype)
                 base = plan.base_pages
                 if base is None:  # ZERO/PRIVATE-only tensor: free base
-                    base = jnp.zeros((plan.n_pages, plan.page_elems), dtype)
+                    base = jnp.zeros((plan.n_pages, *page), dtype)
                 out = overlay_patch_device(
                     base, priv,
                     jnp.asarray(plan.kinds, jnp.int32),
@@ -335,11 +337,14 @@ class DeviceImageCache:
     # ----------------------------------------------------------------- API
     def get_pages(self, base: BaseImage, tensor_name: str, n_pages: int,
                   page_elems: int, dtype) -> Optional[object]:
-        """Device (n_pages, page_elems) base pages for one tensor, building
-        and charging the entry on first use.  Returns None when the entry
-        cannot be served (page-size mismatch, tensor absent from the base,
-        or the ledger cannot admit the bytes even after reclaim) — the
-        caller falls back to the host path for that tensor."""
+        """Device ``(n_pages, rows, LANES)`` base pages for one tensor (the
+        overlay kernel's tiling), building and charging the entry on first
+        use.  Returns None when the entry cannot be served (page-size
+        mismatch, tensor absent from the base, or the ledger cannot admit
+        the bytes even after reclaim) — the caller falls back to the host
+        path for that tensor."""
+        from repro.kernels.overlay_patch.kernel import page_shape
+
         dtype = np.dtype(dtype)
         page_bytes = page_elems * dtype.itemsize
         key = (base.name, tensor_name, dtype.str, int(n_pages), int(page_elems))
@@ -359,7 +364,7 @@ class DeviceImageCache:
         host[: len(raw)] = raw[: n_pages * page_bytes]
         import jax
 
-        dev = self.install(host.view(dtype).reshape(n_pages, page_elems))
+        dev = self.install(host.view(dtype).reshape(n_pages, *page_shape(page_elems)))
         jax.block_until_ready(dev)
         nbytes = int(getattr(dev, "nbytes", n_pages * page_bytes))
         region = None

@@ -39,12 +39,9 @@ def make_mesh_from_plan(plan: MeshPlan, devices: Optional[List] = None):
     devices = devices if devices is not None else jax.devices()
     n = int(np.prod(plan.shape))
     dev = np.asarray(devices[:n]).reshape(plan.shape)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:  # pre-AxisType jax: Auto is the only behaviour
-        return jax.sharding.Mesh(dev, plan.axes)
     return jax.sharding.Mesh(
         dev, plan.axes,
-        axis_types=(axis_type.Auto,) * len(plan.axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(plan.axes),
     )
 
 

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.overlay import KIND_PRIVATE, IntervalTable
-from repro.kernels.overlay_patch.kernel import overlay_patch_kernel
+from repro.kernels.overlay_patch.kernel import overlay_patch_kernel, page_shape
 from repro.kernels.overlay_patch.ref import overlay_patch_ref
 
 
@@ -71,8 +71,15 @@ def overlay_patch(
     src: jax.Array,
     interpret: bool = False,
 ) -> jax.Array:
-    """(n_pages, page_elems) patched output on device."""
-    return overlay_patch_kernel(base, priv, kinds, src, interpret=interpret)
+    """Patched ``(n_pages, *page)`` output on device.  Flat
+    ``(n_pages, page_elems)`` pages are viewed as the kernel's
+    ``(rows, LANES)`` tiles and handed back flat."""
+    tile = page_shape(int(np.prod(base.shape[1:])))
+    out = overlay_patch_kernel(
+        base.reshape(base.shape[0], *tile), priv.reshape(priv.shape[0], *tile),
+        kinds, src, interpret=interpret,
+    )
+    return out.reshape(base.shape)
 
 
 @lru_cache(maxsize=1)
@@ -86,10 +93,11 @@ def overlay_patch_device(
     kinds: jax.Array,
     src: jax.Array,
 ) -> jax.Array:
-    """Serving-path overlay patch: one fused on-device pass, dispatched by
-    backend.  TPU runs the Pallas kernel (scalar-prefetch page table in
-    SMEM); every other backend runs the jitted oracle — same math, same
-    output, compiled gather instead of per-page interpret steps."""
+    """Serving-path overlay patch over ``(n_pages, rows, LANES)`` pages: one
+    fused on-device pass, dispatched by backend.  TPU runs the Pallas
+    kernel (scalar-prefetch page table in SMEM) and raises if it cannot;
+    every other backend runs the jitted oracle — same math, same output,
+    compiled gather instead of per-page interpret steps."""
     if jax.default_backend() == "tpu":
         return overlay_patch_kernel(base, priv, kinds, src)
     return _ref_jit()(base, priv, kinds, src)

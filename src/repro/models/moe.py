@@ -29,18 +29,10 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.sharding.partition import ParamSpec, current_rules, logical_to_spec
 
-try:
-    from jax import shard_map as _shard_map
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_vma=False)
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                              check_rep=False)
 
 W_LOGICAL = {
     "w_gate": ("expert", "fsdp", "model"),

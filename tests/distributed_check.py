@@ -23,9 +23,9 @@ from repro.train.steps import TrainStepConfig, init_train_state, make_train_step
 
 
 def mesh_2d():
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
-    return make_mesh_compat((2, 4), ("data", "model"))
+    return make_mesh((2, 4), ("data", "model"))
 
 
 def check_moe_and_embed():

@@ -14,6 +14,7 @@ from repro.configs import get_config
 from repro.core import ChunkStore, SpiceRestorer
 from repro.ft.manager import CheckpointManager
 from repro.ft.publish import DeltaPublishCallback
+from repro.launch.serve import finetune
 from repro.serve.cluster import ClusterRouter, FunctionCatalog
 from repro.serve.deploy import (
     ColocatedTrainer,
@@ -27,28 +28,6 @@ from repro.models import lm
 
 ARCH = "qwen1.5-0.5b"
 PROMPT = np.array([[3, 1, 4, 1, 5, 9]], dtype=np.int32)
-
-
-def _finetune(cfg, params, scale: float):
-    """The repo's standard partial-fine-tune perturbation (benchmarks
-    idiom): dirty the top ~40% of the stacked layers + final_norm, leaving
-    the rest byte-identical to the parent — the delta publish should pay
-    for roughly that fraction only."""
-    params = dict(params)
-    params["pattern"] = list(params["pattern"])
-    params["final_norm"] = params["final_norm"] + scale
-
-    def bump(a):
-        a = np.asarray(a)
-        if a.ndim >= 1 and a.shape[0] == cfg.pattern_reps:
-            cut = int(cfg.pattern_reps * 0.6)
-            a = a.copy()
-            a[cut:] = a[cut:] * (1.0 + scale)
-        return a
-
-    for pi in range(len(cfg.pattern)):
-        params["pattern"][pi] = jax.tree.map(bump, params["pattern"][pi])
-    return params
 
 
 @pytest.fixture(scope="module")
@@ -93,7 +72,7 @@ def test_versioned_publish_shares_base_chunks(deployed, tmp_path):
 
     before = store.audit()  # also asserts the invariant pre-publish
     rec = deploy.publish_version(
-        "dp-a", cfg, _finetune(cfg, zoo["dp-a"], 0.01), step=1
+        "dp-a", cfg, finetune(cfg, zoo["dp-a"], 0.01), step=1
     )
     after = store.audit()
 
@@ -107,7 +86,7 @@ def test_versioned_publish_shares_base_chunks(deployed, tmp_path):
     # the version is a real registered function restorable on any node
     assert catalog.registry.get(rec.name).jif_path == rec.jif_path
     state, _, _, _ = SpiceRestorer().restore(rec.jif_path)
-    ref = layerwise_state(cfg, _finetune(cfg, zoo["dp-a"], 0.01))
+    ref = layerwise_state(cfg, finetune(cfg, zoo["dp-a"], 0.01))
     for a, b in zip(_leaves(ref), _leaves(state)):
         np.testing.assert_array_equal(a, b)
 
@@ -117,7 +96,7 @@ def test_canary_fraction_deterministic_under_seed(deployed, tmp_path):
     catalog, cfg, d, zoo, store = deployed
     deploy = RolloutController(catalog, seed=123, dirpath=str(tmp_path))
     deploy.track("dp-b")
-    rec = deploy.publish_version("dp-b", cfg, _finetune(cfg, zoo["dp-b"], 0.02))
+    rec = deploy.publish_version("dp-b", cfg, finetune(cfg, zoo["dp-b"], 0.02))
 
     deploy.begin_canary("dp-b", rec.version, fraction=0.3)
     seq1 = [deploy.resolve("dp-b") for _ in range(400)]
@@ -149,7 +128,7 @@ def test_promote_rollback_byte_identity(deployed, tmp_path):
     catalog, cfg, d, zoo, store = deployed
     deploy = RolloutController(catalog, seed=5, dirpath=str(tmp_path))
     deploy.track("dp-c")
-    tuned = _finetune(cfg, zoo["dp-c"], 0.03)
+    tuned = finetune(cfg, zoo["dp-c"], 0.03)
     rec = deploy.publish_version("dp-c", cfg, tuned, step=2)
     deploy.begin_canary("dp-c", rec.version, fraction=0.5)
 
@@ -181,7 +160,7 @@ def test_retired_version_gc_leaves_cas_clean(deployed, tmp_path):
     deploy = RolloutController(catalog, seed=9, dirpath=str(tmp_path))
     deploy.track("dp-d")
     before = store.audit()
-    rec = deploy.publish_version("dp-d", cfg, _finetune(cfg, zoo["dp-d"], 0.04))
+    rec = deploy.publish_version("dp-d", cfg, finetune(cfg, zoo["dp-d"], 0.04))
     deploy.begin_canary("dp-d", rec.version, fraction=0.25)
     deploy.rollback("dp-d")  # gate failed: reject the canary
 
@@ -209,7 +188,7 @@ def test_canary_gate_promotes_over_router(deployed, tmp_path):
     router = _router(catalog)
     deploy = RolloutController(catalog, seed=11, dirpath=str(tmp_path)).attach(router)
     deploy.track("dp-e")
-    rec = deploy.publish_version("dp-e", cfg, _finetune(cfg, zoo["dp-e"], 0.05))
+    rec = deploy.publish_version("dp-e", cfg, finetune(cfg, zoo["dp-e"], 0.05))
     deploy.begin_canary("dp-e", rec.version, fraction=0.5)
 
     # the router resolves the logical name through the controller
@@ -227,7 +206,7 @@ def test_canary_gate_promotes_over_router(deployed, tmp_path):
     assert ok and deploy.current("dp-e").version == rec.version
 
     # a failing gate rejects and keeps the lineage where it was
-    rec3 = deploy.publish_version("dp-e", cfg, _finetune(cfg, zoo["dp-e"], 0.06))
+    rec3 = deploy.publish_version("dp-e", cfg, finetune(cfg, zoo["dp-e"], 0.06))
     deploy.begin_canary("dp-e", rec3.version, fraction=0.5)
 
     class AlwaysBad:
@@ -307,7 +286,7 @@ def test_checkpoint_callback_publishes_versions(deployed, tmp_path):
     mgr = CheckpointManager(str(tmp_path / "ckpt"), async_save=False,
                             callbacks=[cb])
     for step in range(4):  # 4 saves, every=2 -> 2 published versions
-        state = {"params": _finetune(cfg, zoo["dp-f"], 0.001 * (step + 1)),
+        state = {"params": finetune(cfg, zoo["dp-f"], 0.001 * (step + 1)),
                  "opt": {"count": np.int32(step)}}
         mgr.save(step, state, blocking=True)
     assert [r.step for r in cb.published] == [0, 2]
