@@ -1,0 +1,6 @@
+"""Share of the traced cold window with no operation on the chip, %."""
+from chipbench import readers
+
+
+def read(run):
+    return readers.idle_share(run, "cold")
