@@ -24,9 +24,10 @@ storage; the scheduler stays agnostic of JIF layout.
 from __future__ import annotations
 
 import threading
-import time
 from collections import deque
 from typing import Callable, Deque, Dict, List, Optional, Tuple
+
+from repro.core.spans import NO_REQ, span
 
 
 class _TensorJob:
@@ -50,10 +51,14 @@ class IOStream:
         priority: int = 0,
         on_complete: Optional[Callable[[], None]] = None,
         region=None,
+        span_args: Optional[Dict] = None,
     ):
         self.sched = sched
         self.name = name
         self.priority = priority
+        # ``function`` and ``req`` of the invocation that owns this stream,
+        # carried by every span of its reads and uploads
+        self.span_args = span_args or {"function": name, "req": NO_REQ}
         # optional ledger region (repro.core.memory.MemoryRegion): storage
         # bytes this stream reads are recorded as in-flight fill against
         # it, so the node's memory ledger sees prefetch progress live.  The
@@ -141,7 +146,6 @@ class PrefetchIOScheduler:
             "streams_opened": 0,
             "streams_completed": 0,
             "demand_boosts": 0,
-            "busy_s": 0.0,
         }
 
     # ------------------------------------------------------------- streams
@@ -152,13 +156,15 @@ class PrefetchIOScheduler:
         on_complete: Optional[Callable[[], None]] = None,
         inline: bool = False,
         region=None,
+        span_args: Optional[Dict] = None,
     ) -> IOStream:
         """``inline`` streams are never served by the reader thread — the
         caller drains them synchronously via :meth:`drain_inline`.
         ``region`` (optional ledger region) receives in-flight I/O
-        accounting for every storage byte this stream reads."""
+        accounting for every storage byte this stream reads.
+        ``span_args`` (``function`` and ``req``) label the stream's spans."""
         stream = IOStream(self, name, priority=priority, on_complete=on_complete,
-                          region=region)
+                          region=region, span_args=span_args)
         with self._cv:
             if self._shutdown:
                 raise RuntimeError("scheduler is shut down")
@@ -246,9 +252,8 @@ class PrefetchIOScheduler:
         return ready[self._rr]
 
     def _run_op(self, stream: IOStream, op: Callable[[], int]) -> None:
-        t0 = time.perf_counter()
-        nbytes = int(op() or 0)
-        dt = time.perf_counter() - t0
+        with span("spice.read", **stream.span_args):
+            nbytes = int(op() or 0)
         region = stream.region
         if region is not None and nbytes:
             region.note_io(nbytes)
@@ -257,7 +262,6 @@ class PrefetchIOScheduler:
             stream.stats["bytes_read"] += nbytes
             self.stats["io_ops"] += 1
             self.stats["bytes_read"] += nbytes
-            self.stats["busy_s"] += dt
 
     def _maybe_complete(self, stream: IOStream) -> None:
         with self._cv:

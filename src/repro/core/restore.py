@@ -46,7 +46,6 @@ from repro.core.treeutil import unflatten_state
 @dataclasses.dataclass
 class RestoreStats:
     metadata_s: float = 0.0
-    first_tensor_s: float = 0.0
     working_set_s: float = 0.0  # all working-set tensors resident (phase 1)
     total_s: float = 0.0
     bytes_read: int = 0
@@ -93,11 +92,6 @@ class RestoreStats:
         with self._lock:
             for k, v in deltas.items():
                 setattr(self, k, getattr(self, k) + v)
-
-    def set_once(self, field: str, value) -> None:
-        with self._lock:
-            if not getattr(self, field):
-                setattr(self, field, value)
 
     def mark_working_set(self, working_set_s: float) -> None:
         with self._lock:
@@ -243,6 +237,7 @@ class SpiceRestorer:
         memory: Optional[NodeMemoryManager] = None,
         device_path=None,
         chunks: Optional[NodeChunkCache] = None,
+        span_args: Optional[Dict[str, Any]] = None,
     ):
         """``transform`` runs on the scheduler's reader thread per completed
         tensor (e.g. jnp.asarray = eager device install, off the critical
@@ -278,7 +273,10 @@ class SpiceRestorer:
         local disk read), peer hits (interconnect transfer), and misses —
         only the missing chunks are pulled from the image store, and each
         pull ingests into the cache so K deltas of one base cost ~1 base
-        read across the node/cluster, not K."""
+        read across the node/cluster, not K.
+
+        ``span_args`` (``function`` and ``req`` of the invocation that owns
+        the restore) label the spans of its reads and uploads."""
         self.pool = pool or BufferPool()
         self.node_cache = node_cache or NodeImageCache()
         self.io_chunk_bytes = io_chunk_bytes
@@ -290,6 +288,7 @@ class SpiceRestorer:
         self.memory = memory
         self.device_path = device_path
         self.chunks = chunks
+        self.span_args = span_args
         # (ws_region, residual_region) of the LAST restore() call — the
         # node scheduler transfers these onto the FunctionInstance, which
         # releases them on eviction (restorers are per-restore on that path)
@@ -494,8 +493,6 @@ class SpiceRestorer:
                 region = region_ws if t.name in ws_names else region_res
                 if region is not None:
                     region.populate(t.nbytes)
-            if reused:
-                stats.set_once("first_tensor_s", time.perf_counter() - t0)
         except BaseException:
             _release_regions()
             r.close()
@@ -514,13 +511,14 @@ class SpiceRestorer:
                 if plan is not None:
                     dp.upload.upload_fused(
                         handles[name], plan, buffers.pop(name, None),
-                        stats=stats, release=rel,
+                        stats=stats, release=rel, span_args=stream.span_args,
                     )
                 else:
                     dp.upload.upload_full(
                         handles[name], buffers.pop(name),
                         shape=tuple(t.shape), dtype=t.dtype,
                         nbytes=t.nbytes, stats=stats, release=rel,
+                        span_args=stream.span_args,
                     )
             else:
                 arr = buffers[name][: t.nbytes].view(np.dtype(t.dtype))
@@ -545,7 +543,6 @@ class SpiceRestorer:
             region = region_ws if name in ws_names else region_res
             if region is not None:
                 region.populate(t.nbytes)
-            stats.set_once("first_tensor_s", time.perf_counter() - t0)
             if name in ws_names:
                 # the stream serves one tensor at a time, so this counter
                 # only ever moves on the serving thread
@@ -724,6 +721,7 @@ class SpiceRestorer:
                 priority=self.stream_priority,
                 inline=not self.pipelined,
                 region=region_ws,
+                span_args=self.span_args,
             )
         except BaseException:
             _release_regions()

@@ -46,6 +46,7 @@ from repro.core.memory import (
     MemoryPressureError,
     NodeMemoryManager,
 )
+from repro.core.spans import NO_REQ, span
 
 
 def _default_install(arr: np.ndarray):
@@ -129,13 +130,17 @@ class UploadStream:
                 )
                 self._thread.start()
 
-    def _submit(self, job: Callable[[], None]) -> None:
+    def _span_args(self, span_args: Optional[Dict]) -> Dict:
+        return span_args or {"function": self.name, "req": NO_REQ}
+
+    def _submit(self, job: Callable[[], None], span_args: Dict) -> None:
         with self._cv:
             if self._closed:
                 raise RuntimeError(f"upload stream {self.name!r} is closed")
             self._pending += 1
         self._ensure_worker()
-        self._q.put(job)  # blocks while the ring is full (backpressure)
+        with span("spice.ring_wait", **span_args):
+            self._q.put(job)  # blocks while the ring is full (backpressure)
 
     def _loop(self) -> None:
         while True:
@@ -160,10 +165,13 @@ class UploadStream:
 
     # ----------------------------------------------------------------- API
     def upload_full(self, handle, buf: np.ndarray, *, shape, dtype: str,
-                    nbytes: int, stats=None, release=None) -> None:
+                    nbytes: int, stats=None, release=None,
+                    span_args: Optional[Dict] = None) -> None:
         """Enqueue a whole-tensor upload: the staging buffer holds the full
         host tensor (base memcpy + private reads + zero pages); the device
-        copy happens on the uploader thread, overlapped with further reads."""
+        copy happens on the uploader thread, overlapped with further reads.
+        ``span_args`` (``function`` and ``req``) label the upload's spans."""
+        span_args = self._span_args(span_args)
 
         def job():
             import jax
@@ -174,7 +182,8 @@ class UploadStream:
                 t0 = time.perf_counter()
                 if self.simulate_bw:
                     time.sleep(nbytes / self.simulate_bw)
-                arr = self.install(view)
+                with span("spice.upload.put", **span_args):
+                    arr = self.install(view)
                 jax.block_until_ready(arr)
                 dt = time.perf_counter() - t0
                 handle.set(arr)
@@ -189,15 +198,16 @@ class UploadStream:
                 if release is not None:
                     release(buf)
 
-        self._submit(job)
+        self._submit(job, span_args)
 
     def upload_fused(self, handle, plan: FusedPlan,
                      buf: Optional[np.ndarray], *, stats=None,
-                     release=None) -> None:
+                     release=None, span_args: Optional[Dict] = None) -> None:
         """Enqueue a fused upload+patch: only the compact private pages in
         ``buf`` cross to the device; the full tensor materializes there via
         the overlay-patch kernel against the HBM-resident base pages
         (``plan.base_pages``; ZERO pages cost nothing)."""
+        span_args = self._span_args(span_args)
 
         def job():
             import jax
@@ -219,7 +229,8 @@ class UploadStream:
                         .view(dtype)
                         .reshape(plan.n_priv, *page)
                     )
-                    priv = self.install(priv_host)
+                    with span("spice.upload.put", **span_args):
+                        priv = self.install(priv_host)
                 else:
                     priv = jnp.zeros((1, *page), dtype)
                 base = plan.base_pages
@@ -251,7 +262,7 @@ class UploadStream:
                 if release is not None and buf is not None:
                     release(buf)
 
-        self._submit(job)
+        self._submit(job, span_args)
 
     def flush(self, timeout: Optional[float] = None) -> bool:
         """Block until every enqueued upload landed (tests/benchmarks)."""

@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.configs.base import LayerSpec, ModelConfig
 from repro.core.restore import RestoreStats, TensorHandle
+from repro.core.spans import NO_REQ, span
 from repro.models import blocks
 from repro.models.layers import embed, rmsnorm, unembed
 
@@ -85,6 +86,9 @@ def _cached(key, build):
     return fn
 
 
+# The programs' Python names name their modules in a device trace:
+# jit_serve_embed, jit_serve_layer_prefill, jit_serve_layer_decode and
+# jit_serve_head.
 def _layer_fn(cfg: ModelConfig, spec: LayerSpec, mode: str):
     def build():
         def fn(p, x, positions, cache, pos):
@@ -94,26 +98,30 @@ def _layer_fn(cfg: ModelConfig, spec: LayerSpec, mode: str):
             )
             return x, c
 
+        fn.__name__ = fn.__qualname__ = f"serve_layer_{mode}"
         return jax.jit(fn)
 
     return _cached(("layer", cfg.name, spec, mode), build)
 
 
 def _embed_fn(cfg: ModelConfig):
-    return _cached(
-        ("embed", cfg.name),
-        lambda: jax.jit(lambda p, toks: embed(cfg, p, toks, jnp.float32)),
-    )
+    def build():
+        def serve_embed(p, toks):
+            return embed(cfg, p, toks, jnp.float32)
+
+        return jax.jit(serve_embed)
+
+    return _cached(("embed", cfg.name), build)
 
 
 def _head_fn(cfg: ModelConfig):
     def build():
-        def fn(p_embed, p_norm, x):
+        def serve_head(p_embed, p_norm, x):
             x = rmsnorm(x[:, -1:], p_norm, cfg.norm_eps)
             logits = unembed(cfg, p_embed, x, jnp.float32)
             return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
 
-        return jax.jit(fn)
+        return jax.jit(serve_head)
 
     return _cached(("head", cfg.name), build)
 
@@ -127,45 +135,58 @@ def wait_tree(tree):
     )
 
 
-def state_layer(state, i, resolve):
-    return resolve(state["layers"][i])
-
-
-def generate(cfg, getter, state, prompt: np.ndarray, max_new: int):
+def generate(cfg, getter, state, prompt: np.ndarray, max_new: int,
+             span_args: Optional[Dict[str, Any]] = None):
     """Layer-gated generation: each layer waits for exactly its params.
-    Returns (tokens, ttft_s).  Read-only over ``state``; safe to run
-    concurrently from several invocations sharing one instance."""
+    Returns (tokens, ttft_s); ``ttft_s`` ends with the first token on the
+    host.  Read-only over ``state``; safe to run concurrently from several
+    invocations sharing one instance.  ``span_args`` (``function`` and
+    ``req``) label its spans: ``serve.generate`` up to the first token,
+    and one ``serve.resolve`` and ``serve.dispatch`` per parameter wait
+    and program call, prefill and decode."""
     # default resolver materializes any lazy leaves (access-trace
     # proxies); a no-op for already-installed device arrays
     resolve = getter or (
         lambda t: jax.tree.map(lambda l: jnp.asarray(np.asarray(l)) if not isinstance(l, jax.Array) else l, t)
     )
+    args = span_args or {"function": cfg.name, "req": NO_REQ}
+
+    def fetch(tree, **where):
+        with span("serve.resolve", **args, **where):
+            return resolve(tree)
+
+    def call(fn, *xs, **where):
+        with span("serve.dispatch", **args, **where):
+            return fn(*xs)
+
     specs = layer_sequence(cfg)
     B, S = prompt.shape
     positions = np.broadcast_to(np.arange(S, dtype=np.int32), (B, S))
 
     t0 = time.perf_counter()
-    p_embed = resolve(state["embed"])
-    x = _embed_fn(cfg)(p_embed, prompt)
-    caches = []
-    for i, spec in enumerate(specs):
-        p_i = resolve(state["layers"][i])
-        x, c = _layer_fn(cfg, spec, "prefill")(p_i, x, positions, None, None)
-        caches.append(c)
-    p_norm = resolve(state["final_norm"])
-    tok = _head_fn(cfg)(p_embed, p_norm, x)
+    with span("serve.generate", **args):
+        p_embed = fetch(state["embed"], part="embed")
+        x = call(_embed_fn(cfg), p_embed, prompt, part="embed")
+        caches = []
+        for i, spec in enumerate(specs):
+            p_i = fetch(state["layers"][i], part="layer", layer=i)
+            x, c = call(_layer_fn(cfg, spec, "prefill"), p_i, x, positions, None, None,
+                        part="layer", layer=i)
+            caches.append(c)
+        p_norm = fetch(state["final_norm"], part="final_norm")
+        tok = call(_head_fn(cfg), p_embed, p_norm, x, part="head")
+        out = [np.asarray(tok)]
     ttft = time.perf_counter() - t0
-    out = [np.asarray(tok)]
 
     pos = S
     for _ in range(max_new - 1):
-        x = _embed_fn(cfg)(p_embed, np.asarray(tok)[:, None])
+        x = call(_embed_fn(cfg), p_embed, out[-1][:, None], part="embed")
         dpos = np.broadcast_to(np.int32(pos), (B, 1))
         for i, spec in enumerate(specs):
-            x, caches[i] = _layer_fn(cfg, spec, "decode")(
-                state_layer(state, i, resolve), x, dpos, caches[i], jnp.int32(pos)
-            )
-        tok = _head_fn(cfg)(p_embed, p_norm, x)
+            p_i = fetch(state["layers"][i], part="layer", layer=i)
+            x, caches[i] = call(_layer_fn(cfg, spec, "decode"), p_i, x, dpos, caches[i],
+                                jnp.int32(pos), part="layer", layer=i)
+        tok = call(_head_fn(cfg), p_embed, p_norm, x, part="head")
         out.append(np.asarray(tok))
         pos += 1
     return np.stack(out, axis=1), ttft

@@ -33,6 +33,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from repro.core.spans import NO_REQ
+
 __all__ = [
     "QosClass",
     "Invocation",
@@ -181,6 +183,7 @@ class InvocationHandle:
     def __init__(self, invocation: Invocation, node: str = ""):
         self.invocation = invocation
         self.node = node
+        self.req = NO_REQ  # the admitting node's sequence number
         self._lock = threading.Lock()
         self._events: List[Tuple[str, float]] = []
         self._done_ev = threading.Event()

@@ -58,6 +58,7 @@ from repro.core.memory import (
     NodeMemoryManager,
 )
 from repro.core.restore import RestoreStats, estimate_rerestore_cost
+from repro.core.spans import span
 from repro.core.trace import AccessRecorder
 from repro.core.upload import DeviceImageCache, DevicePath, UploadStream
 from repro.serve.invocation import (
@@ -141,6 +142,17 @@ class NodeLoad:
 
 # a prewarm invocation's result carries no generation output
 _EMPTY_TOKENS = np.zeros((0,), np.int32)
+
+
+def _role(result: InvokeResult) -> str:
+    """An invocation's part in its function's state, for its span: warm
+    (served by a resident instance), owner (ran the restore), joined (rode
+    another invocation's restore) or payload (a colocated compute thunk)."""
+    if result.mode == "payload":
+        return "payload"
+    if not result.cold:
+        return "warm"
+    return "joined" if result.joined else "owner"
 
 
 def _cancel_collateral(exc: BaseException) -> bool:
@@ -413,6 +425,7 @@ class NodeScheduler:
             self._fn_active[fname] = self._fn_active.get(fname, 0) + 1
             seq = self._seq
             self._seq += 1
+            handle.req = seq  # labels every span of this invocation
             # record BEFORE the entry becomes poppable: a free worker may
             # claim it the instant the lock drops, and the timeline must
             # still read ADMITTED -> PLACED -> <work>
@@ -516,7 +529,10 @@ class NodeScheduler:
             result = None
             for attempt in range(3):
                 try:
-                    result = self._invoke_inner(inv, handle, t_submit)
+                    with span("serve.invoke", function=inv.function,
+                              req=handle.req) as invoke:
+                        result = self._invoke_inner(inv, handle, t_submit)
+                        invoke.set_metadata(role=_role(result))
                     break
                 except BaseException as exc:
                     if handle.cancel_requested:
@@ -935,6 +951,7 @@ class NodeScheduler:
         t0 = time.perf_counter()
         queue_s = t0 - t_submit
         self._bump("invocations")
+        span_args = {"function": fname, "req": handle.req}
         inst = self._get_instance(fname, spec, cfg)
         role = None
         tree = getter = None
@@ -995,7 +1012,8 @@ class NodeScheduler:
                         total_s=time.perf_counter() - t0,
                         function=fname, queue_s=queue_s, node=self.name,
                     )
-                toks, ttft = generate(cfg, getter, tree, prompt, max_new_tokens)
+                toks, ttft = generate(cfg, getter, tree, prompt, max_new_tokens,
+                                      span_args)
                 dt = time.perf_counter() - t0
                 self._bump("warm_hits")
                 return InvokeResult(
@@ -1017,7 +1035,8 @@ class NodeScheduler:
                         function=fname, queue_s=queue_s, node=self.name,
                     )
                 handle.record(EVT_RUNNING)
-                toks, ttft = generate(cfg, getter, tree, prompt, max_new_tokens)
+                toks, ttft = generate(cfg, getter, tree, prompt, max_new_tokens,
+                                      span_args)
                 dt = time.perf_counter() - t0
                 self._bump("joined_restores")
                 return InvokeResult(
@@ -1044,6 +1063,7 @@ class NodeScheduler:
                 state, stats, getter, regions, stream = self._cold_restore(
                     spec, mode, inv.simulate_read_bw, preloaded, pinned_region,
                     io_priority=inv.qos.io_priority, on_working_set=_ws_ready,
+                    span_args=span_args,
                 )
                 with inst.cond:
                     inst.publish_restore(state, getter, stats, regions)
@@ -1069,7 +1089,7 @@ class NodeScheduler:
                     toks, ttft = _EMPTY_TOKENS, 0.0
                 else:
                     toks, ttft = generate(
-                        cfg, getter, state, prompt, max_new_tokens
+                        cfg, getter, state, prompt, max_new_tokens, span_args
                     )
                 ttl = self.keepalive.ttl_for(spec)
                 now = time.time()
@@ -1267,7 +1287,7 @@ class NodeScheduler:
 
     def _cold_restore(self, spec: FunctionSpec, mode: str, sim_bw=None,
                       preloaded=None, pinned_region=None, io_priority: int = 0,
-                      on_working_set=None):
+                      on_working_set=None, span_args=None):
         """Returns (state, stats, getter, (ws_region, residual_region),
         stream).  Spice restores reserve their regions up front through the
         node ledger — a restore that cannot fit fails fast
@@ -1277,7 +1297,9 @@ class NodeScheduler:
         baseline modes re-read everything, so it is released here.
         ``io_priority`` (the QoS class's stream priority) ranks this
         restore's reads at the shared arbiter; ``stream`` is the live
-        prefetch stream for cancellation (None for baseline modes)."""
+        prefetch stream for cancellation (None for baseline modes).
+        ``span_args`` label a spice restore's spans with the owning
+        invocation's ``function`` and ``req``."""
         if pinned_region is not None and mode not in ("spice", "spice_sync"):
             pinned_region.release()
             pinned_region = None
@@ -1289,7 +1311,7 @@ class NodeScheduler:
                 transform=transform, simulate_read_bw=sim_bw,
                 iosched=self.iosched, memory=self.memory,
                 stream_priority=io_priority, device_path=device_path,
-                chunks=self.chunks,
+                chunks=self.chunks, span_args=span_args,
             )
             state, meta, handles, stats = restorer.restore(
                 spec.jif_path, wait=False, preloaded=preloaded,
@@ -1302,7 +1324,7 @@ class NodeScheduler:
                 transform=transform, simulate_read_bw=sim_bw,
                 iosched=self.iosched, memory=self.memory,
                 stream_priority=io_priority, device_path=device_path,
-                chunks=self.chunks,
+                chunks=self.chunks, span_args=span_args,
             )
             state, meta, handles, stats = restorer.restore(
                 spec.jif_path, wait=True, preloaded=preloaded,

@@ -291,10 +291,14 @@ def test_iosched_boost_priority_is_qos_weighted():
     def op(n=1000):
         return lambda: n
 
+    running = threading.Event()
     batch = sched.open_stream("batch", priority=-1)
-    lat = sched.open_stream("lat", priority=2)
-    batch.submit("gate", [lambda: (gate.wait(5), 0)[1]],
+    batch.submit("gate", [lambda: (running.set(), gate.wait(5), 0)[2]],
                  lambda: order.append("b-gate"))
+    # the reader holds the gate before the latency stream opens; else it
+    # may serve that higher-priority stream whole before the boosts below
+    assert running.wait(5)
+    lat = sched.open_stream("lat", priority=2)
     for i in range(3):
         batch.submit(f"b{i}", [op()], (lambda n=f"b{i}": order.append(n)))
     for i in range(3):
