@@ -278,8 +278,12 @@ class PrefetchIOScheduler:
             if stream in self._streams:
                 self._streams.remove(stream)
             self.stats["streams_completed"] += 1
-        if stream._on_complete is not None:
-            stream._on_complete()
+        # run once, then dropped: a restore's callback holds its tensor
+        # handles, which hold the stream, a cycle that would keep every
+        # restored tensor alive until the cycle collector runs
+        on_complete, stream._on_complete = stream._on_complete, None
+        if on_complete is not None:
+            on_complete()
         stream._done.set()
 
     def _fail_stream(self, stream: IOStream, exc: BaseException) -> None:

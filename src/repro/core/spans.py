@@ -19,8 +19,9 @@ belongs to; :data:`NO_REQ` where no invocation owns the work).
                       final norm's parameters
     spice.read        prefetch reader: one storage op of a restore stream
     spice.ring_wait   prefetch reader: handing a tensor to the upload ring
-                      (blocks while every slot is in flight)
-    spice.upload.put  uploader: one host-to-device put
+                      (blocks while ``depth`` jobs are outstanding)
+    spice.upload.put  ring's issuer: one host-to-device put
+    spice.upload.land ring's lander: one wait for an issued job to land
 """
 from __future__ import annotations
 

@@ -5,16 +5,19 @@ pays a synchronous per-tensor device copy on the prefetcher thread, so the
 read stream stalls behind every upload (serialization-bound, not
 read-bandwidth-bound).  This module closes that gap:
 
-* :class:`UploadStream` — a double-buffered host→HBM upload engine.  The
-  prefetcher's finalize enqueues an upload job and returns to reading; a
-  dedicated uploader thread performs the device transfers.  The ring is
-  bounded (``depth`` slots, default 2): while one slot uploads, the next
-  is staged, and the reader only blocks when BOTH are in flight — uploads
-  overlap with ongoing disk reads, and (because completion is tracked per
-  tensor) with layer-gated decode in the function instance.  The pool's
-  pre-zeroed staging buffers are the pinned-slot analogue: jobs hand them
-  back to the pool after the device copy lands, re-zeroing on the uploader
-  thread, off every critical path.
+* :class:`UploadStream` — a bounded host→HBM upload ring.  The
+  prefetcher's finalize enqueues an upload job and returns to reading; an
+  issuer thread dispatches each job's device transfer without waiting for
+  it, and a lander thread waits for the jobs to land, in order, and
+  resolves their handles.  At most ``depth`` jobs are outstanding
+  (default :data:`UPLOAD_DEPTH`), queued and in flight together, so
+  several transfers cross at once and the reader only blocks when all
+  ``depth`` are outstanding — uploads overlap with each other, with
+  ongoing disk reads, and (because completion is tracked per tensor) with
+  layer-gated decode in the function instance.  The pool's pre-zeroed
+  staging buffers are the pinned-slot analogue: the lander hands each one
+  back to the pool only after the transfer that reads it landed,
+  re-zeroing it there, off the reader's and the issuer's paths.
 
 * :class:`DeviceImageCache` — base images resident in HBM once per node.
   Each (image, tensor) entry holds the base's pages on device, charged to
@@ -32,6 +35,8 @@ read-bandwidth-bound).  This module closes that gap:
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 import queue
 import threading
 import time
@@ -49,6 +54,11 @@ from repro.core.memory import (
 from repro.core.spans import NO_REQ, span
 
 
+# Jobs the upload ring keeps outstanding: the fewest transfers in flight
+# that reached the host link's rate on a TPU v5e (PERF.md, section 5).
+UPLOAD_DEPTH = 8
+
+
 def _default_install(arr: np.ndarray):
     """Host array -> device array.  MUST copy: on CPU ``jnp.asarray`` can
     alias the staging buffer, which the pool recycles and re-zeroes (on TPU
@@ -56,6 +66,19 @@ def _default_install(arr: np.ndarray):
     import jax.numpy as jnp
 
     return jnp.array(arr, copy=True)
+
+
+@functools.lru_cache(maxsize=1)
+def _unpage():
+    """Jitted: the restored tensor from its patched ``(n_pages, ...)``
+    pages, flattened, trimmed to the tensor's length and reshaped — one
+    dispatch per fused job."""
+    import jax
+
+    def unpage(pages, shape):
+        return pages.reshape(-1)[: math.prod(shape)].reshape(shape)
+
+    return jax.jit(unpage, static_argnums=1)
 
 
 @dataclasses.dataclass
@@ -83,34 +106,64 @@ class FusedPlan:
         return self.n_priv * self.page_bytes
 
 
+@dataclasses.dataclass(eq=False)
+class _Job:
+    """One upload on the ring: ``issue`` dispatches its transfers (and, for
+    a fused job, the patch) without waiting, leaving the handle's array in
+    ``arr`` and every transfer that reads ``buf`` in ``reads``."""
+
+    issue: Callable[["_Job"], None]
+    handle: object
+    buf: Optional[np.ndarray]
+    release: Optional[Callable]
+    uploaded: int  # bytes that cross to HBM
+    patched: int  # tensor bytes the patch materializes (fused jobs)
+    fused: bool
+    stats: object
+    span_args: Dict
+    t_issue: float = 0.0
+    arr: object = None
+    reads: Tuple = ()
+    error: Optional[BaseException] = None
+
+
 class UploadStream:
     """Bounded host→HBM upload ring shared by every restore on a node.
 
-    One daemon uploader thread drains a queue of at most ``depth`` jobs.
-    ``submit`` blocks the producer (the prefetch reader thread) only when
-    the ring is full — the documented trade-off: brief reader stalls bound
-    the staging memory in flight instead of letting uploads queue
-    unboundedly.  Each job resolves exactly one :class:`TensorHandle`
-    (``set`` on success, ``fail`` on error), so execution gates on real
-    device arrays and a failed upload never hangs a waiter."""
+    Two daemon threads share the work.  The issuer takes submitted jobs in
+    order and dispatches each one's transfer (and patch) without waiting for
+    it; the lander takes issued jobs in the same order, waits for each to
+    land, hands its staging buffer back, and only then resolves its
+    :class:`TensorHandle` — so handles resolve in submit order, to arrays on
+    the device, and a staging buffer is never released under a transfer
+    that reads it.  At most ``depth`` jobs are outstanding (submitted and
+    not yet landed, queued and in flight together); ``submit`` blocks the
+    producer (the prefetch reader thread) only while all of them are, the
+    trade-off that bounds the transfers in flight.  Each job resolves
+    exactly one handle (``set`` on success, ``fail`` on error: at once for
+    an error while issuing, after the wait for one while landing), so a
+    failed upload never hangs a waiter."""
 
-    def __init__(self, depth: int = 2, name: str = "upload-stream",
+    def __init__(self, depth: int = UPLOAD_DEPTH, name: str = "upload-stream",
                  install: Optional[Callable] = None,
                  simulate_bw: Optional[float] = None):
         """``simulate_bw`` (bytes/s) models the host→device interconnect
-        roofline the same way ``simulate_read_bw`` models storage: each job
-        sleeps for the bytes it actually moves (private pages only for
-        fused jobs — the fast path's economy shows up as shorter sleeps).
+        roofline the same way ``simulate_read_bw`` models storage: the
+        issuer sleeps for the bytes each job actually moves (private pages
+        only for fused jobs — the fast path's economy shows up as shorter
+        sleeps), so the simulated link moves one job's bytes at a time.
         Labeled benchmark runs only; None on real hardware."""
         self.name = name
         self.depth = max(1, int(depth))
         self.install = install or _default_install
         self.simulate_bw = simulate_bw
-        self._q: "queue.Queue" = queue.Queue(maxsize=self.depth)
-        self._lock = threading.Lock()
-        self._cv = threading.Condition(self._lock)
-        self._pending = 0  # queued + executing jobs
-        self._thread: Optional[threading.Thread] = None
+        self._issue_q: "queue.Queue" = queue.Queue()
+        self._land_q: "queue.Queue" = queue.Queue()
+        self._cv = threading.Condition(threading.Lock())
+        self._pending = 0  # submitted jobs that have not landed
+        self._in_flight = 0  # issued jobs that have not landed
+        self._last_land = 0.0  # lander only
+        self._threads: Tuple[threading.Thread, ...] = ()
         self._closed = False
         self.stats = {
             "uploads": 0,
@@ -119,49 +172,120 @@ class UploadStream:
             "patched_bytes": 0,
             "upload_s": 0.0,
             "failures": 0,
+            "issued_while_busy": 0,  # issued while an earlier job was in flight
+            "in_flight_max": 0,
         }
 
     # ------------------------------------------------------------ internals
-    def _ensure_worker(self) -> None:
-        with self._cv:
-            if self._thread is None or not self._thread.is_alive():
-                self._thread = threading.Thread(
-                    target=self._loop, name=f"{self.name}-uploader", daemon=True
-                )
-                self._thread.start()
-
     def _span_args(self, span_args: Optional[Dict]) -> Dict:
         return span_args or {"function": self.name, "req": NO_REQ}
 
-    def _submit(self, job: Callable[[], None], span_args: Dict) -> None:
-        with self._cv:
-            if self._closed:
-                raise RuntimeError(f"upload stream {self.name!r} is closed")
-            self._pending += 1
-        self._ensure_worker()
-        with span("spice.ring_wait", **span_args):
-            self._q.put(job)  # blocks while the ring is full (backpressure)
+    def _submit(self, job: _Job) -> None:
+        with span("spice.ring_wait", **job.span_args):
+            with self._cv:
+                # backpressure: wait while ``depth`` jobs are outstanding
+                self._cv.wait_for(
+                    lambda: self._closed or self._pending < self.depth
+                )
+                if self._closed:
+                    raise RuntimeError(f"upload stream {self.name!r} is closed")
+                self._pending += 1
+                if not self._threads:
+                    self._threads = tuple(
+                        threading.Thread(target=loop, name=f"{self.name}-{role}",
+                                         daemon=True)
+                        for loop, role in ((self._issue_loop, "issuer"),
+                                           (self._land_loop, "lander"))
+                    )
+                    for th in self._threads:
+                        th.start()
+        self._issue_q.put(job)
 
-    def _loop(self) -> None:
+    def _fail(self, job: _Job, exc: BaseException) -> None:
+        job.error = exc
+        with self._cv:
+            self.stats["failures"] += 1
+        job.handle.fail(exc)
+
+    def _issue_loop(self) -> None:
         while True:
-            job = self._q.get()
+            job = self._issue_q.get()
+            if job is None:
+                self._land_q.put(None)
+                return
+            self._issue(job)
+            job = None  # hold no tensor while idle
+
+    def _issue(self, job: _Job) -> None:
+        job.t_issue = time.perf_counter()
+        try:
+            if self.simulate_bw:
+                time.sleep(job.uploaded / self.simulate_bw)
+            job.issue(job)
+        except BaseException as exc:  # noqa: BLE001 — typed via handle
+            self._fail(job, exc)
+        with self._cv:
+            if job.error is None:
+                self.stats["issued_while_busy"] += int(self._in_flight > 0)
+                self.stats["in_flight_max"] = max(
+                    self.stats["in_flight_max"], self._in_flight + 1
+                )
+            self._in_flight += 1
+        self._land_q.put(job)
+
+    def _land_loop(self) -> None:
+        while True:
+            job = self._land_q.get()
             if job is None:
                 return
-            try:
-                job()
-            finally:
-                with self._cv:
-                    self._pending -= 1
-                    self._cv.notify_all()
+            self._land(job)
+            job = None  # hold no landed tensor while idle
 
-    def _note(self, dt: float, uploaded: int, patched: int, fused: bool) -> None:
+    def _land(self, job: _Job) -> None:
+        import jax
+
+        try:
+            with span("spice.upload.land", **job.span_args):
+                if job.error is None:
+                    try:
+                        jax.block_until_ready(job.arr)
+                    except BaseException as exc:  # noqa: BLE001
+                        self._fail(job, exc)
+                else:
+                    # failed while issuing: let whatever reached the
+                    # device land before its source is recycled
+                    for x in job.reads:
+                        try:
+                            jax.block_until_ready(x)
+                        except Exception:  # noqa: BLE001 — reported at issue
+                            pass
+            land = time.perf_counter()
+            if job.release is not None and job.buf is not None:
+                job.release(job.buf)
+            if job.error is None:
+                # ring time this landing added: the restore's sum is the
+                # union of its jobs' in-flight intervals
+                dt = max(0.0, land - max(job.t_issue, self._last_land))
+                self._last_land = land
+                job.handle.set(job.arr)
+                self._note(job, dt)
+        finally:
+            with self._cv:
+                self._in_flight -= 1
+                self._pending -= 1
+                self._cv.notify_all()
+
+    def _note(self, job: _Job, dt: float) -> None:
         with self._cv:
             self.stats["uploads"] += 1
             self.stats["upload_s"] += dt
-            self.stats["uploaded_bytes"] += uploaded
-            if fused:
+            self.stats["uploaded_bytes"] += job.uploaded
+            if job.fused:
                 self.stats["fused_patches"] += 1
-                self.stats["patched_bytes"] += patched
+                self.stats["patched_bytes"] += job.patched
+        if job.stats is not None:
+            job.stats.add(upload_s=dt, uploaded_bytes=job.uploaded,
+                          patched_on_device_bytes=job.patched)
 
     # ----------------------------------------------------------------- API
     def upload_full(self, handle, buf: np.ndarray, *, shape, dtype: str,
@@ -169,36 +293,20 @@ class UploadStream:
                     span_args: Optional[Dict] = None) -> None:
         """Enqueue a whole-tensor upload: the staging buffer holds the full
         host tensor (base memcpy + private reads + zero pages); the device
-        copy happens on the uploader thread, overlapped with further reads.
-        ``span_args`` (``function`` and ``req``) label the upload's spans."""
+        copy is issued on the issuer thread, overlapped with further reads
+        and earlier transfers.  ``span_args`` (``function`` and ``req``)
+        label the upload's spans."""
         span_args = self._span_args(span_args)
 
-        def job():
-            import jax
+        def issue(job: _Job) -> None:
+            view = buf[:nbytes].view(np.dtype(dtype))
+            view = view.reshape(shape) if shape else view.reshape(())
+            with span("spice.upload.put", **span_args):
+                job.arr = self.install(view)
+            job.reads = (job.arr,)
 
-            try:
-                view = buf[:nbytes].view(np.dtype(dtype))
-                view = view.reshape(shape) if shape else view.reshape(())
-                t0 = time.perf_counter()
-                if self.simulate_bw:
-                    time.sleep(nbytes / self.simulate_bw)
-                with span("spice.upload.put", **span_args):
-                    arr = self.install(view)
-                jax.block_until_ready(arr)
-                dt = time.perf_counter() - t0
-                handle.set(arr)
-                self._note(dt, nbytes, 0, fused=False)
-                if stats is not None:
-                    stats.add(upload_s=dt, uploaded_bytes=nbytes)
-            except BaseException as exc:  # noqa: BLE001 — typed via handle
-                with self._cv:
-                    self.stats["failures"] += 1
-                handle.fail(exc)
-            finally:
-                if release is not None:
-                    release(buf)
-
-        self._submit(job, span_args)
+        self._submit(_Job(issue, handle, buf, release, nbytes, 0, False,
+                          stats, span_args))
 
     def upload_fused(self, handle, plan: FusedPlan,
                      buf: Optional[np.ndarray], *, stats=None,
@@ -209,60 +317,35 @@ class UploadStream:
         (``plan.base_pages``; ZERO pages cost nothing)."""
         span_args = self._span_args(span_args)
 
-        def job():
-            import jax
+        def issue(job: _Job) -> None:
             import jax.numpy as jnp
 
             from repro.kernels.overlay_patch.kernel import page_shape
             from repro.kernels.overlay_patch.ops import overlay_patch_device
 
-            try:
-                dtype = np.dtype(plan.dtype)
-                page = page_shape(plan.page_elems)
-                t0 = time.perf_counter()
-                if self.simulate_bw:
-                    # only the private pages cross the interconnect
-                    time.sleep(plan.priv_bytes / self.simulate_bw)
-                if plan.n_priv and buf is not None:
-                    priv_host = (
-                        buf[: plan.priv_bytes]
-                        .view(dtype)
-                        .reshape(plan.n_priv, *page)
-                    )
-                    with span("spice.upload.put", **span_args):
-                        priv = self.install(priv_host)
-                else:
-                    priv = jnp.zeros((1, *page), dtype)
-                base = plan.base_pages
-                if base is None:  # ZERO/PRIVATE-only tensor: free base
-                    base = jnp.zeros((plan.n_pages, *page), dtype)
-                out = overlay_patch_device(
-                    base, priv,
-                    jnp.asarray(plan.kinds, jnp.int32),
-                    jnp.asarray(plan.src, jnp.int32),
+            dtype = np.dtype(plan.dtype)
+            page = page_shape(plan.page_elems)
+            if plan.n_priv and buf is not None:
+                priv_host = (
+                    buf[: plan.priv_bytes]
+                    .view(dtype)
+                    .reshape(plan.n_priv, *page)
                 )
-                n_elems = plan.nbytes // dtype.itemsize
-                arr = out.reshape(-1)[:n_elems]
-                arr = arr.reshape(plan.shape) if plan.shape else arr.reshape(())
-                jax.block_until_ready(arr)
-                dt = time.perf_counter() - t0
-                handle.set(arr)
-                self._note(dt, plan.priv_bytes, plan.nbytes, fused=True)
-                if stats is not None:
-                    stats.add(
-                        upload_s=dt,
-                        uploaded_bytes=plan.priv_bytes,
-                        patched_on_device_bytes=plan.nbytes,
-                    )
-            except BaseException as exc:  # noqa: BLE001 — typed via handle
-                with self._cv:
-                    self.stats["failures"] += 1
-                handle.fail(exc)
-            finally:
-                if release is not None and buf is not None:
-                    release(buf)
+                with span("spice.upload.put", **span_args):
+                    priv = self.install(priv_host)
+                job.reads = (priv,)
+            else:
+                priv = jnp.zeros((1, *page), dtype)
+            base = plan.base_pages
+            if base is None:  # ZERO/PRIVATE-only tensor: free base
+                base = jnp.zeros((plan.n_pages, *page), dtype)
+            # the page plan goes as host int32 arrays: the jitted call
+            # moves them with its arguments, in one dispatch
+            out = overlay_patch_device(base, priv, plan.kinds, plan.src)
+            job.arr = _unpage()(out, plan.shape)
 
-        self._submit(job, span_args)
+        self._submit(_Job(issue, handle, buf, release, plan.priv_bytes,
+                          plan.nbytes, True, stats, span_args))
 
     def flush(self, timeout: Optional[float] = None) -> bool:
         """Block until every enqueued upload landed (tests/benchmarks)."""
@@ -270,16 +353,19 @@ class UploadStream:
             return self._cv.wait_for(lambda: self._pending == 0, timeout)
 
     def close(self, timeout: float = 5.0) -> None:
-        """Drain outstanding uploads and stop the worker (idempotent)."""
+        """Drain outstanding uploads, issued and landed, and stop both
+        threads (idempotent)."""
         with self._cv:
             if self._closed:
                 return
             self._closed = True
-            th = self._thread
+            self._cv.notify_all()  # a producer blocked on a full ring
+            threads = self._threads
         self.flush(timeout)
-        if th is not None and th.is_alive():
-            self._q.put(None)
-            th.join(timeout)
+        if threads:
+            self._issue_q.put(None)  # the issuer passes it on to the lander
+            for th in threads:
+                th.join(timeout)
 
     def snapshot_stats(self) -> Dict[str, float]:
         with self._cv:

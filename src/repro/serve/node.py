@@ -60,7 +60,12 @@ from repro.core.memory import (
 from repro.core.restore import RestoreStats, estimate_rerestore_cost
 from repro.core.spans import span
 from repro.core.trace import AccessRecorder
-from repro.core.upload import DeviceImageCache, DevicePath, UploadStream
+from repro.core.upload import (
+    UPLOAD_DEPTH,
+    DeviceImageCache,
+    DevicePath,
+    UploadStream,
+)
 from repro.serve.invocation import (
     EVT_ADMITTED,
     EVT_PLACED,
@@ -232,7 +237,7 @@ class NodeScheduler:
         reap_interval_s: Optional[float] = None,
         admission: Optional[AdmissionController] = None,
         install: object = "eager",
-        upload_depth: int = 2,
+        upload_depth: int = UPLOAD_DEPTH,
         simulate_upload_bw: Optional[float] = None,
         chunks: Optional[NodeChunkCache] = None,
         load_ttl_s: float = 0.0,
@@ -242,8 +247,9 @@ class NodeScheduler:
         thread, the default), "host" (tensors stay host numpy), "fused"
         (device fast path: UploadStream + DeviceImageCache, private pages
         upload and overlay-patch against HBM-resident bases), or a callable
-        (custom per-tensor transform, eager-style).  ``upload_depth`` sizes
-        the fused path's upload ring (staging slots in flight);
+        (custom per-tensor transform, eager-style).  ``upload_depth`` bounds
+        the fused path's upload ring: jobs submitted and not yet landed on
+        the device, queued and in flight together;
         ``simulate_upload_bw`` models the interconnect roofline on the ring
         (labeled benchmark runs only, like ``simulate_read_bw``).
         ``chunks`` (a :class:`repro.core.chunkstore.NodeChunkCache` over

@@ -18,7 +18,7 @@ from repro.serve.invocation import Invocation
 
 PROMPT = np.array([[3, 1, 4, 1, 5, 9, 2, 6]], dtype=np.int32)
 SPANS = ("serve.invoke", "serve.generate", "serve.dispatch", "serve.resolve",
-         "spice.read", "spice.ring_wait", "spice.upload.put")
+         "spice.read", "spice.ring_wait", "spice.upload.put", "spice.upload.land")
 
 
 @pytest.fixture(scope="module")
@@ -106,10 +106,12 @@ def test_spans_of_one_request_share_its_req(traced):
     assert cold_req != warm_req
     roles = {ev[3]["req"]: ev[3]["role"] for ev in _of(events, "serve.invoke")}
     assert roles == {cold_req: "owner", warm_req: "warm"}
-    # the restore's reads and uploads run on the reader and uploader
+    # the restore's reads and uploads run on the reader and the ring's
     # threads, labelled with the invocation that owns the restore
-    for name in ("spice.read", "spice.ring_wait", "spice.upload.put"):
+    for name in ("spice.read", "spice.ring_wait", "spice.upload.put", "spice.upload.land"):
         assert _of(events, name) == _of(events, name, cold_req)
+    # the lander waits once for every job, put or not
+    assert len(_of(events, "spice.upload.land")) >= len(_of(events, "spice.upload.put"))
     # the worker's spans of each request lie inside its serve.invoke
     for req in (cold_req, warm_req):
         (invoke,) = _of(events, "serve.invoke", req)

@@ -1,7 +1,10 @@
 """Device-restore fast path: UploadStream, DeviceImageCache, the fused
 restore's equality with the eager path, install-policy selection on the
 node, and the device-resident re-restore economics."""
+import gc
 import threading
+import time
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +19,7 @@ from repro.core import (
     SpiceRestorer,
     snapshot,
 )
-from repro.core.restore import TensorHandle
+from repro.core.restore import RestoreStats, TensorHandle
 from repro.core.treeutil import flatten_state
 from repro.core.upload import DeviceImageCache, DevicePath, UploadStream
 from repro.models import lm
@@ -103,6 +106,206 @@ def test_upload_stream_failure_fails_handle():
         up.upload_full(TensorHandle("x", (1,), "float32"),
                        np.zeros(4, np.uint8), shape=(1,),
                        dtype="float32", nbytes=4)
+
+
+class _InFlight:
+    """A device array whose transfer lands once ``landed`` is set (or its
+    deadline passes); ``block_until_ready`` waits for that, as a real
+    transfer's does."""
+
+    def __init__(self, arr, landed=None, deadline=None, error=None):
+        self.arr, self.landed, self.deadline, self.error = arr, landed, deadline, error
+
+    def block_until_ready(self):
+        if self.landed is not None:
+            assert self.landed.wait(10)
+        if self.deadline is not None:
+            time.sleep(max(0.0, self.deadline - time.perf_counter()))
+        if self.error is not None:
+            raise self.error
+        return self
+
+
+class _OrderedHandle(TensorHandle):
+    def __init__(self, name, order):
+        super().__init__(name, (4,), "float32")
+        self.order = order
+
+    def set(self, arr):
+        self.order.append(self.name)
+        super().set(arr)
+
+
+def _gated_ring(n, depth, errors=()):
+    """A ring whose i-th install lands when ``gates[i]`` is set; returns
+    the ring, the gates, the installs issued so far, and submit(i)."""
+    gates = [threading.Event() for _ in range(n)]
+    issued = []
+
+    def install(view):
+        i = len(issued)
+        issued.append(i)
+        err = RuntimeError(f"transfer {i} lost") if i in errors else None
+        return _InFlight(np.array(view), landed=gates[i], error=err)
+
+    up = UploadStream(depth=depth, name="t-gated", install=install)
+    return up, gates, issued
+
+
+def _submit(up, name, order, release=None, value=0.0, stats=None):
+    h = _OrderedHandle(name, order)
+    buf = np.frombuffer(np.full(4, value, np.float32).tobytes(), np.uint8).copy()
+    up.upload_full(h, buf, shape=(4,), dtype="float32", nbytes=16,
+                   release=release, stats=stats)
+    return h, buf
+
+
+def _wait_for(pred, timeout=10.0):
+    t_end = time.perf_counter() + timeout
+    while not pred():
+        assert time.perf_counter() < t_end
+        time.sleep(0.002)
+
+
+def test_upload_stream_issues_next_job_before_last_lands():
+    """Job k+1 is issued while job k is still in flight; handles still
+    resolve in submit order, only once their own job landed."""
+    up, gates, issued = _gated_ring(4, depth=4)
+    order = []
+    try:
+        handles = [_submit(up, f"t{i}", order, value=float(i))[0] for i in range(4)]
+        _wait_for(lambda: len(issued) == 4)  # all four issued, none landed
+        assert not any(h.ready for h in handles)
+        for i in (3, 2, 1):  # later jobs land first: nothing resolves yet
+            gates[i].set()
+        time.sleep(0.05)
+        assert not any(h.ready for h in handles)
+        gates[0].set()
+        assert up.flush(timeout=10)
+        assert order == ["t0", "t1", "t2", "t3"]
+        for i, h in enumerate(handles):
+            assert np.all(h.wait(timeout=5).arr.view(np.float32) == float(i))
+        st = up.snapshot_stats()
+        assert st["in_flight_max"] >= 2
+        assert st["issued_while_busy"] > 0
+        assert st["uploads"] == 4 and st["failures"] == 0
+    finally:
+        for g in gates:
+            g.set()
+        up.close()
+
+
+def test_upload_stream_depth_bounds_jobs_not_landed():
+    """``submit`` blocks while ``depth`` jobs are outstanding, in flight
+    or queued."""
+    up, gates, issued = _gated_ring(3, depth=2)
+    order = []
+    try:
+        _submit(up, "t0", order)
+        _submit(up, "t1", order)
+        third = threading.Thread(target=_submit, args=(up, "t2", order))
+        third.start()
+        _wait_for(lambda: len(issued) == 2)
+        time.sleep(0.05)
+        assert third.is_alive() and len(issued) == 2
+        gates[0].set()
+        third.join(10)
+        assert not third.is_alive()
+        gates[1].set()
+        gates[2].set()
+        assert up.flush(timeout=10)
+        assert up.snapshot_stats()["in_flight_max"] == 2
+    finally:
+        for g in gates:
+            g.set()
+        up.close()
+
+
+def test_upload_stream_releases_each_buffer_after_its_own_job_lands():
+    """With several jobs in flight, each staging buffer returns to the
+    pool only after the transfer that reads it landed."""
+    up, gates, issued = _gated_ring(4, depth=4)
+    order, released = [], []
+
+    def release(buf):
+        i = next(k for k, b in enumerate(bufs) if b is buf)
+        released.append((i, gates[i].is_set()))
+
+    bufs = []
+    try:
+        for i in range(4):
+            bufs.append(_submit(up, f"t{i}", order, release=release)[1])
+        _wait_for(lambda: len(issued) == 4)
+        time.sleep(0.05)
+        assert released == []
+        for i in (2, 0, 3, 1):
+            gates[i].set()
+        assert up.flush(timeout=10)
+        assert released == [(0, True), (1, True), (2, True), (3, True)]
+    finally:
+        for g in gates:
+            g.set()
+        up.close()
+
+
+def test_upload_stream_landing_failure_fails_only_its_handle():
+    up, gates, _issued = _gated_ring(3, depth=3, errors={1})
+    order, released = [], []
+    try:
+        handles = [_submit(up, f"t{i}", order, release=released.append,
+                           value=float(i))[0] for i in range(3)]
+        for g in gates:
+            g.set()
+        assert up.flush(timeout=10)
+        with pytest.raises(RuntimeError, match="restore of t1 failed"):
+            handles[1].wait(timeout=5)
+        assert handles[0].wait(timeout=5).arr.view(np.float32)[0] == 0.0
+        assert handles[2].wait(timeout=5).arr.view(np.float32)[0] == 2.0
+        assert len(released) == 3  # the failed job's buffer too
+        st = up.snapshot_stats()
+        assert st["failures"] == 1 and st["uploads"] == 2
+    finally:
+        up.close()
+
+
+def test_upload_stream_close_drains_jobs_in_flight():
+    up, gates, issued = _gated_ring(3, depth=3)
+    order, released = [], []
+    handles = [_submit(up, f"t{i}", order, release=released.append)[0]
+               for i in range(3)]
+    _wait_for(lambda: len(issued) == 3)
+    opener = threading.Timer(0.1, lambda: [g.set() for g in gates])
+    opener.start()
+    up.close(timeout=10)
+    opener.join()
+    assert order == ["t0", "t1", "t2"] and len(released) == 3
+    assert all(h.ready for h in handles)
+    assert not any(th.is_alive() for th in up._threads)
+
+
+def test_upload_stream_upload_time_is_the_union_of_overlapping_jobs():
+    """Jobs in flight together add their ring time once: the stream's and
+    the restore's ``upload_s`` stay within the burst's wall time."""
+    land_s = 0.05
+
+    def install(view):
+        return _InFlight(np.array(view), deadline=time.perf_counter() + land_s)
+
+    up = UploadStream(depth=8, install=install)
+    stats = RestoreStats()
+    order = []
+    try:
+        t0 = time.perf_counter()
+        for i in range(8):
+            _submit(up, f"t{i}", order, stats=stats)
+        assert up.flush(timeout=10)
+        wall = time.perf_counter() - t0
+        st = up.snapshot_stats()
+        assert land_s <= st["upload_s"] <= wall < 8 * land_s
+        assert stats.upload_s == pytest.approx(st["upload_s"])
+        assert st["issued_while_busy"] > 0
+    finally:
+        up.close()
 
 
 # -------------------------------------------------------- DeviceImageCache
@@ -202,6 +405,29 @@ def test_fused_delta_restore_matches_eager(tmp_path):
     assert st.uploaded_bytes < ref_stats.bytes_read + ref_stats.base_bytes
     assert st.patched_on_device_bytes == sum(a.nbytes for a in ft.values())
     assert st.bytes_read == 2 * ps  # reads also shrank to the private runs
+
+
+def test_restored_tree_is_freed_without_the_cycle_collector(tmp_path):
+    """Once a restore completed, only its caller holds its tensors: with the
+    cycle collector off, dropping the tree frees them, so a cold restore's
+    device tree does not stay in HBM until the next collection."""
+    path = str(tmp_path / "f.jif")
+    snapshot({"w0": np.arange(512, dtype=np.float32),
+              "w1": np.ones(300, np.float32)}, path, page_size=512)
+    up = UploadStream()
+    r = SpiceRestorer(node_cache=NodeImageCache(),
+                      device_path=DevicePath(upload=up, images=DeviceImageCache()))
+    gc.disable()
+    try:
+        state, _, handles, st = r.restore(path, wait=True)
+        assert st.wait_complete(10)
+        refs = [weakref.ref(h) for h in handles.values()]
+        del state, handles
+        _wait_for(lambda: all(ref() is None for ref in refs))
+    finally:
+        gc.enable()
+        r.iosched.shutdown()
+        up.close()
 
 
 # --------------------------------------------------- node install policies
