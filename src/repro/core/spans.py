@@ -20,7 +20,11 @@ belongs to; :data:`NO_REQ` where no invocation owns the work).
     spice.read        prefetch reader: one storage op of a restore stream
     spice.ring_wait   prefetch reader: handing a tensor to the upload ring
                       (blocks while ``depth`` jobs are outstanding)
-    spice.upload.put  ring's issuer: one host-to-device put
+    spice.upload.issue ring's issuer: one job's whole issue, fused or full
+                      (its put, and a fused job's patch and un-paging
+                      dispatches)
+    spice.upload.put  ring's issuer: one host-to-device put, inside its
+                      job's spice.upload.issue
     spice.upload.land ring's lander: one wait for an issued job to land
 """
 from __future__ import annotations

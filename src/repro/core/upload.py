@@ -218,12 +218,13 @@ class UploadStream:
 
     def _issue(self, job: _Job) -> None:
         job.t_issue = time.perf_counter()
-        try:
-            if self.simulate_bw:
-                time.sleep(job.uploaded / self.simulate_bw)
-            job.issue(job)
-        except BaseException as exc:  # noqa: BLE001 — typed via handle
-            self._fail(job, exc)
+        with span("spice.upload.issue", **job.span_args):
+            try:
+                if self.simulate_bw:
+                    time.sleep(job.uploaded / self.simulate_bw)
+                job.issue(job)
+            except BaseException as exc:  # noqa: BLE001 — typed via handle
+                self._fail(job, exc)
         with self._cv:
             if job.error is None:
                 self.stats["issued_while_busy"] += int(self._in_flight > 0)

@@ -135,10 +135,10 @@ def _causal_conv(xs, w, b, K, S, compute_dtype):
 
 
 def _gated_out(cfg, p, y, z, compute_dtype):
-    # RMSNorm(y) * silu(z), then output projection
-    y = rmsnorm(y, p["norm_w"], cfg.norm_eps) * jax.nn.silu(
-        z.astype(jnp.float32)
-    ).astype(compute_dtype)
+    # RMSNorm(y * silu(z)), the published gated norm (mamba_ssm
+    # RMSNormGated, norm_before_gate=False), then output projection
+    y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(compute_dtype),
+                p["norm_w"], cfg.norm_eps)
     return jnp.einsum("bsi,id->bsd", y, p["out_proj"].astype(compute_dtype))
 
 

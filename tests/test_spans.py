@@ -18,7 +18,8 @@ from repro.serve.invocation import Invocation
 
 PROMPT = np.array([[3, 1, 4, 1, 5, 9, 2, 6]], dtype=np.int32)
 SPANS = ("serve.invoke", "serve.generate", "serve.dispatch", "serve.resolve",
-         "spice.read", "spice.ring_wait", "spice.upload.put", "spice.upload.land")
+         "spice.read", "spice.ring_wait", "spice.upload.issue", "spice.upload.put",
+         "spice.upload.land")
 
 
 @pytest.fixture(scope="module")
@@ -40,22 +41,33 @@ def _ask(node, cfg, max_new=2):
 
 
 @pytest.fixture(scope="module")
-def traced(node, tmp_path_factory):
+def ring_jobs():
+    """The upload ring's jobs of the traced cold request, fused and full."""
+    return {}
+
+
+@pytest.fixture(scope="module")
+def traced(node, tmp_path_factory, ring_jobs):
     """Events of one cold, then one warm request: (name, start_ns, end_ns,
     args) per span of the program, and the two requests' ``req``."""
     node, cfg = node
     assert node.scheduler.drain_residual(60)
     node.evict()
+    ring = node.scheduler.upload_stream
+    before = ring.snapshot_stats()
     logdir = str(tmp_path_factory.mktemp("trace"))
     jax.profiler.start_trace(logdir)
     try:
         cold_req, cold = _ask(node, cfg)
         # the restore's residual tail lands before the warm request
         assert node.scheduler.drain_residual(60)
+        after = ring.snapshot_stats()
         warm_req, warm = _ask(node, cfg)
     finally:
         jax.profiler.stop_trace()
     assert cold.cold and not cold.joined and not warm.cold
+    fused = after["fused_patches"] - before["fused_patches"]
+    ring_jobs.update(fused=fused, full=after["uploads"] - before["uploads"] - fused)
     path = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"), recursive=True)[0]
     events = []
     for plane in jax.profiler.ProfileData.from_file(path).planes:
@@ -118,6 +130,20 @@ def test_spans_of_one_request_share_its_req(traced):
         for name in ("serve.generate", "serve.dispatch", "serve.resolve"):
             evs = _of(events, name, req)
             assert evs and _inside(evs, invoke) == evs
+
+
+def test_issue_span_wraps_every_ring_job_and_its_put(traced, ring_jobs):
+    events, cold_req, _ = traced
+    issues = _of(events, "spice.upload.issue")
+    assert issues == _of(events, "spice.upload.issue", cold_req)
+    assert all(args["function"] == serve.TUNED_FN for *_, args in issues)
+    # one issue per job of the restore, fused and full alike, each landed once
+    assert ring_jobs["fused"] > 0 and ring_jobs["full"] > 0
+    assert len(issues) == ring_jobs["fused"] + ring_jobs["full"]
+    assert len(issues) == len(_of(events, "spice.upload.land"))
+    # every put happens inside the issue of its job
+    puts = _of(events, "spice.upload.put")
+    assert puts and all(any(i[1] <= p[1] and p[2] <= i[2] for i in issues) for p in puts)
 
 
 class _SlowFetch:

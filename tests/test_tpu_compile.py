@@ -20,8 +20,8 @@ from repro.models.layers import embed_specs
 from repro.serve.instance import _embed_fn, _head_fn, _layer_fn
 from repro.sharding.partition import abstract_from_specs
 
-ARCH = "qwen1.5-0.5b"
-PROMPT_LEN = 16
+ARCHS = ("qwen1.5-0.5b", "mamba2-780m")  # the benchmark's configurations
+PROMPT_LEN = 1024  # the benchmark's prompts
 PAGE_BYTES = 64 * 1024  # the JIF default page
 
 
@@ -77,9 +77,9 @@ def test_overlay_patch_kernel_compiles(one_chip, n_pages, n_priv, dtype):
     assert "tpu_custom_call" in hlo  # the Pallas kernel, not a fallback
 
 
-@pytest.fixture(scope="module")
-def full_cfg() -> ModelConfig:
-    return get_config(ARCH)  # the published widths, not .reduced()
+@pytest.fixture(scope="module", params=ARCHS)
+def full_cfg(request) -> ModelConfig:
+    return get_config(request.param)  # the published widths, not .reduced()
 
 
 def _layer_shapes(cfg, one_chip):
