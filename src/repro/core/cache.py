@@ -33,6 +33,7 @@ class BaseImage:
         self.page_size = page_size
         self._bytes: Dict[str, np.ndarray] = {}
         self._digests: Dict[str, np.ndarray] = {}
+        self._specs: Dict[str, Tuple[Tuple[int, ...], np.dtype]] = {}
 
     @classmethod
     def from_state(cls, name: str, state, page_size: int = overlay.DEFAULT_PAGE) -> "BaseImage":
@@ -42,6 +43,7 @@ class BaseImage:
             raw = np.ascontiguousarray(arr).view(np.uint8).reshape(-1)
             img._bytes[lname] = raw.copy()
             img._digests[lname] = overlay.chunk_digests(memoryview(raw), page_size)
+            img._specs[lname] = (tuple(arr.shape), arr.dtype)
         return img
 
     @classmethod
@@ -83,6 +85,13 @@ class BaseImage:
 
     def digests(self, name: str) -> Optional[np.ndarray]:
         return self._digests.get(name)
+
+    def spec(self, name: str) -> Optional[Tuple[Tuple[int, ...], np.dtype]]:
+        """(shape, dtype) the base holds ``name`` at, or None if absent."""
+        return self._specs.get(name)
+
+    def tensor_bytes(self, name: str) -> np.ndarray:
+        return self._bytes[name]
 
     def chunk_bytes(self, name: str, start_chunk: int, n: int) -> np.ndarray:
         raw = self._bytes[name]

@@ -66,6 +66,8 @@ class RestoreStats:
     uploaded_bytes: int = 0           # bytes that actually crossed to HBM
     patched_on_device_bytes: int = 0  # tensor bytes materialized by the kernel
     fused_tensors: int = 0        # planned to patch on device (private pages only)
+    shared_tensors: int = 0       # served as the node's HBM base array (all BASE)
+    shared_bytes: int = 0         # their bytes: no read, no upload, no copy
     full_upload_tensors: int = 0  # planned to assemble on host and upload whole
     # content-addressed dedup: bytes served per tier instead of pulled from
     # the image store, plus the metadata-time plan partition (chunk counts)
@@ -258,7 +260,9 @@ class SpiceRestorer:
         read (into a compact staging buffer) and uploaded; BASE pages come
         from the HBM-resident :class:`DeviceImageCache`, ZERO pages are
         free, and the overlay-patch kernel materializes the full tensor on
-        device — or a full upload (host assembly as usual, whole-tensor
+        device — or a SHARED tensor (every page BASE: the cache's device
+        array of the base tensor is the restored leaf, with no read and no
+        upload) — or a full upload (host assembly as usual, whole-tensor
         upload off the reader thread) when fusion cannot apply: page size
         not a dtype multiple, all-private itable (nothing to fuse), BASE
         pages with no device base available (cache miss under pressure, or
@@ -369,15 +373,18 @@ class SpiceRestorer:
         dp = self.device_path
         plans: Dict[str, Any] = {}   # name -> FusedPlan
         full_upload: set = set()     # device path, whole-tensor upload
+        shared: Dict[str, Any] = {}  # device path, the base's HBM array as is
         if dp is not None:
             try:
-                plans, full_upload = self._plan_device(r, base, reused)
+                plans, full_upload, shared = self._plan_device(r, base, reused)
             except BaseException:
                 if preloaded_region is not None:
                     preloaded_region.release()
                 r.close()
                 raise
             stats.add(fused_tensors=len(plans), full_upload_tensors=len(full_upload))
+        # resident already (pinned or shared): no read op, no ring job
+        served = reused.keys() | shared.keys()
 
         # ---- dedup planning: partition chunk lists by digest -------------
         # Metadata-time only (the itables and digest regions are already
@@ -397,7 +404,7 @@ class SpiceRestorer:
             if have:
                 plan_hits = {"ram": 0, "cas": 0, None: 0}
                 for t in r.tensors:
-                    if t.name in reused or t.name in plans:
+                    if t.name in served or t.name in plans:
                         continue
                     dg = r.digests(t.name)
                     if dg is None:
@@ -465,7 +472,7 @@ class SpiceRestorer:
         try:
             for t in r.tensors:
                 handles[t.name] = TensorHandle(t.name, t.shape, t.dtype)
-                if t.name in reused:
+                if t.name in served:
                     continue
                 plan = plans.get(t.name)
                 if plan is not None:
@@ -476,7 +483,7 @@ class SpiceRestorer:
                 else:
                     buffers[t.name] = self.pool.acquire(t.nbytes)
             ws_remaining = [sum(
-                1 for t in r.tensors if t.name in ws_names and t.name not in reused
+                1 for t in r.tensors if t.name in ws_names and t.name not in served
             )]
             stats.image_bytes = sum(t.nbytes for t in r.tensors)
             stats.ws_tensors = sum(1 for t in r.tensors if t.name in ws_names)
@@ -484,12 +491,20 @@ class SpiceRestorer:
             stats.ws_names = [n for n in order if n in ws_names]
             stats.metadata_s = time.perf_counter() - t0
 
-            # pinned tensors are resident already: serve them with zero I/O
+            # pinned and shared tensors are resident already: serve them
+            # with zero I/O and no upload
             for t in r.tensors:
-                if t.name not in reused:
+                arr = reused.get(t.name)
+                if arr is not None:
+                    stats.add(reused_bytes=t.nbytes, reused_tensors=1)
+                elif t.name in shared:
+                    arr = shared[t.name]
+                    stats.add(shared_bytes=t.nbytes, shared_tensors=1,
+                              base_bytes=t.nbytes)
+                    dp.images.note_base_served(t.nbytes)
+                else:
                     continue
-                handles[t.name].set(reused[t.name])
-                stats.add(reused_bytes=t.nbytes, reused_tensors=1)
+                handles[t.name].set(arr)
                 region = region_ws if t.name in ws_names else region_res
                 if region is not None:
                     region.populate(t.nbytes)
@@ -758,7 +773,7 @@ class SpiceRestorer:
                 if on_working_set is not None:
                     on_working_set()
             for name in order:
-                if name in reused:
+                if name in served:
                     continue
                 stream.submit(name, tensor_ops(name), partial(finalize, name))
             stream.seal()
@@ -785,15 +800,18 @@ class SpiceRestorer:
 
     def _plan_device(
         self, r: JifReader, base: Optional[BaseImage], reused: Dict[str, Any]
-    ) -> Tuple[Dict[str, Any], set]:
-        """Split this image's tensors between the two device-path modes:
-        ``plans`` (name -> FusedPlan: upload private pages only, patch on
-        device) and ``full_upload`` (host-assemble as usual, whole-tensor
-        upload off the reader thread).  Fusion applies when the page
-        views as whole kernel tiles of the dtype and the itable has
-        BASE/ZERO pages to save; BASE pages additionally need the
-        device-resident base — a cache miss under memory pressure falls
-        back to full upload, never fails."""
+    ) -> Tuple[Dict[str, Any], set, Dict[str, Any]]:
+        """Split this image's tensors between the three device-path modes:
+        ``shared`` (name -> the cache's device array of the base tensor:
+        every page is BASE, so the tensor is the base's, shared rather than
+        patched), ``plans`` (name -> FusedPlan: upload private pages only,
+        patch on device) and ``full_upload`` (host-assemble as usual,
+        whole-tensor upload off the reader thread).  Sharing needs the base
+        to hold the tensor at the same shape and dtype.  Fusion applies
+        when the page views as whole kernel tiles of the dtype and the
+        itable has BASE/ZERO pages to save; BASE pages additionally need
+        the device-resident base — a cache miss under memory pressure
+        falls back (shared to fused, fused to full upload), never fails."""
         # imported here: the host-only restore path must not pull in jax
         from repro.core.upload import FusedPlan
         from repro.kernels.overlay_patch.kernel import tiles
@@ -802,6 +820,7 @@ class SpiceRestorer:
         dp = self.device_path
         plans: Dict[str, Any] = {}
         full: set = set()
+        shared: Dict[str, Any] = {}
         ps = r.page_size
         for t in r.tensors:
             if t.name in reused:
@@ -810,12 +829,18 @@ class SpiceRestorer:
             it = r.itable(t.name)
             kinds, src, runs, n_priv = compact_plan_from_itable(it)
             n_pages = it.n_pages
+            is_base = kinds == overlay.KIND_BASE
+            if n_pages and is_base.all() and dp.images is not None and base is not None:
+                arr = dp.images.get_tensor(base, t.name, t.shape, dtype)
+                if arr is not None:
+                    shared[t.name] = arr
+                    continue
             if not tiles(ps, dtype) or n_pages == 0 or n_priv == n_pages:
                 full.add(t.name)  # nothing to fuse (or pages unviewable)
                 continue
             page_elems = ps // dtype.itemsize
             base_pages = None
-            if (kinds == overlay.KIND_BASE).any():
+            if is_base.any():
                 if dp.images is None or base is None:
                     full.add(t.name)
                     continue
@@ -831,7 +856,7 @@ class SpiceRestorer:
                 n_pages=n_pages, n_priv=n_priv, kinds=kinds, src=src,
                 runs=runs, base_pages=base_pages,
             )
-        return plans, full
+        return plans, full, shared
 
     # one bootstrap per parent key at a time: N sibling delta restores that
     # all miss the parent must not each materialize the full image

@@ -20,14 +20,17 @@ read-bandwidth-bound).  This module closes that gap:
   re-zeroing it there, off the reader's and the issuer's paths.
 
 * :class:`DeviceImageCache` — base images resident in HBM once per node.
-  Each (image, tensor) entry holds the base's pages on device, charged to
-  the node ledger under the ``device_image`` kind and evictable via its
-  own reclaim-ladder rung (order 1: after residual tails, before host base
+  Each (image, tensor) entry holds the base's tensor on device, either in
+  its own shape or as the overlay kernel's pages, charged to the node
+  ledger under the ``device_image`` kind and evictable via its own
+  reclaim-ladder rung (order 1: after residual tails, before host base
   images — a dropped device base costs one re-upload from host, never a
-  disk read).  Delta restores then upload ONLY private pages and
-  materialize the full tensor on device with the overlay-patch kernel:
-  BASE pages come from the shared HBM-resident base, ZERO pages are free,
-  and no intermediate full host tensor is ever built.
+  disk read).  A delta restore shares a tensor it left unchanged (every
+  page BASE) as the cache's array: no ring job, no copy.  A tensor with
+  private pages uploads ONLY those and materializes on device with the
+  overlay-patch kernel: BASE pages come from the shared HBM-resident base
+  pages, ZERO pages are free, and no intermediate full host tensor is ever
+  built.
 
 * :class:`DevicePath` — the bundle a :class:`~repro.core.restore
   .SpiceRestorer` takes as its ``device_path=`` mode.
@@ -374,12 +377,15 @@ class UploadStream:
 
 
 class DeviceImageCache:
-    """HBM-resident base pages, shared by every fused restore on a node.
+    """HBM-resident base tensors, shared by every fused restore on a node.
 
-    One entry per (base image, tensor, dtype, page geometry): the base's
-    raw bytes padded to the restored tensor's page count, viewed in the
-    tensor's dtype, installed on device ONCE — the ROADMAP scenario where
-    thousands of fine-tunes of one base share a single HBM-resident copy.
+    Two kinds of entry: per (base image, tensor, dtype, shape) the base's
+    tensor as is (:meth:`get_tensor`, for tensors a delta left unchanged),
+    and per (base image, tensor, dtype, page geometry) its raw bytes padded
+    to the restored tensor's page count (:meth:`get_pages`, the overlay
+    kernel's base).  Each is installed on device ONCE — the ROADMAP
+    scenario where thousands of fine-tunes of one base share a single
+    HBM-resident copy.
     Attached to the node ledger, entries are charged as ``device_image``
     regions and LRU-evicted by the pressure reclaimer (rung
     ``RECLAIM_ORDER``); every entry is recoverable from the host
@@ -398,7 +404,7 @@ class DeviceImageCache:
         self._memory: Optional[NodeMemoryManager] = None
         self.total_bytes = 0
         self.stats = {
-            "hits": 0, "misses": 0, "evictions": 0,
+            "hits": 0, "tensor_hits": 0, "misses": 0, "evictions": 0,
             "built_bytes": 0, "base_bytes_served": 0,
         }
 
@@ -446,25 +452,58 @@ class DeviceImageCache:
         dtype = np.dtype(dtype)
         page_bytes = page_elems * dtype.itemsize
         key = (base.name, tensor_name, dtype.str, int(n_pages), int(page_elems))
-        with self._lock:
-            hit = self._entries.get(key)
-            if hit is not None:
-                self.stats["hits"] += 1
-                self._entries.move_to_end(key)
-                return hit[0]
+        hit = self._lookup(key, "hits")
+        if hit is not None:
+            return hit
         if base.page_size != page_bytes or base.digests(tensor_name) is None:
             return None
-        # build OUTSIDE the lock: pad the base's raw bytes to the restored
-        # tensor's page count (a shorter base cannot own pages past its
-        # length — classify never marks them BASE — so zero padding is safe)
+        # pad the base's raw bytes to the restored tensor's page count (a
+        # shorter base cannot own pages past its length — classify never
+        # marks them BASE — so zero padding is safe)
         raw = base.chunk_bytes(tensor_name, 0, n_pages)
         host = np.zeros(n_pages * page_bytes, np.uint8)
         host[: len(raw)] = raw[: n_pages * page_bytes]
+        host = host.view(dtype).reshape(n_pages, *page_shape(page_elems))
+        return self._admit(key, host, "hits")
+
+    def get_tensor(self, base: BaseImage, tensor_name: str, shape,
+                   dtype) -> Optional[object]:
+        """The base's tensor on device in its own ``shape`` and ``dtype``,
+        building and charging the entry on first use.  A restore serves a
+        tensor whose pages are all BASE as this one array, shared with
+        every other restore of the node: JAX arrays are immutable, so the
+        sharing is copy-on-write.  Returns None when the entry cannot be
+        served (the base holds the tensor at another shape or dtype, hence
+        byte length, or not at all, or the ledger cannot admit the bytes
+        even after reclaim) — the caller takes the ring path instead."""
+        dtype = np.dtype(dtype)
+        shape = tuple(int(d) for d in shape)
+        key = (base.name, tensor_name, dtype.name, shape)
+        hit = self._lookup(key, "tensor_hits")
+        if hit is not None:
+            return hit
+        if base.spec(tensor_name) != (shape, dtype):
+            return None
+        host = base.tensor_bytes(tensor_name).view(dtype).reshape(shape)
+        return self._admit(key, host, "tensor_hits")
+
+    def _lookup(self, key, hit_stat: str) -> Optional[object]:
+        with self._lock:
+            hit = self._entries.get(key)
+            if hit is None:
+                return None
+            self.stats[hit_stat] += 1
+            self._entries.move_to_end(key)
+            return hit[0]
+
+    def _admit(self, key, host: np.ndarray, hit_stat: str) -> Optional[object]:
+        """Install ``host`` on device OUTSIDE the lock, charge it to the
+        ledger and keep it under ``key``; None if the ledger refuses it."""
         import jax
 
-        dev = self.install(host.view(dtype).reshape(n_pages, *page_shape(page_elems)))
+        dev = self.install(host)
         jax.block_until_ready(dev)
-        nbytes = int(getattr(dev, "nbytes", n_pages * page_bytes))
+        nbytes = int(getattr(dev, "nbytes", host.nbytes))
         region = None
         if self._memory is not None:
             # reserve BEFORE taking the cache lock: admission may run the
@@ -472,7 +511,7 @@ class DeviceImageCache:
             try:
                 region = self._memory.reserve(
                     nbytes, KIND_DEVICE_IMAGE,
-                    owner=f"{base.name}/{tensor_name}", block=False,
+                    owner="/".join(map(str, key[:2])), block=False,
                 )
             except MemoryPressureError:
                 return None  # caller falls back to the host path
@@ -481,7 +520,7 @@ class DeviceImageCache:
         with self._lock:
             raced = self._entries.get(key)
             if raced is not None:  # lost a build race: keep the winner
-                self.stats["hits"] += 1
+                self.stats[hit_stat] += 1
                 if region is not None:
                     evicted.append(region)
                 dev = raced[0]

@@ -361,6 +361,41 @@ def test_device_image_cache_pressure_falls_back():
     mem.audit()
 
 
+def test_device_image_cache_tensor_entry_and_mismatches():
+    base, raw = _base_image()
+    mem = NodeMemoryManager(64 << 20)
+    cache = DeviceImageCache()
+    cache.attach(mem)
+    arr = cache.get_tensor(base, "w", raw.shape, np.float32)
+    np.testing.assert_array_equal(np.asarray(arr), raw)
+    assert arr.shape == raw.shape and arr.dtype == raw.dtype
+    assert cache.get_tensor(base, "w", raw.shape, np.float32) is arr
+    # the tensor entry is its own, never the kernel's pages
+    pages = cache.get_pages(base, "w", 4, 128, np.float32)
+    assert pages is not arr
+    st = cache.snapshot_stats()
+    assert st["tensor_hits"] == 1 and st["hits"] == 0 and st["misses"] == 2
+    assert mem.kind_bytes()["device_image"] == cache.resident_bytes() == 2 * raw.nbytes
+    # shape, dtype or length other than the base's, or an absent tensor
+    assert cache.get_tensor(base, "w", (4, 128), np.float32) is None
+    assert cache.get_tensor(base, "w", raw.shape, np.int32) is None
+    assert cache.get_tensor(base, "w", (raw.size - 1,), np.float32) is None
+    assert cache.get_tensor(base, "nope", raw.shape, np.float32) is None
+    assert cache.reclaim(1 << 30) == 2 * raw.nbytes
+    assert mem.kind_bytes()["device_image"] == 0
+    mem.audit()
+
+
+def test_device_image_cache_tensor_pressure_falls_back():
+    base, raw = _base_image()
+    mem = NodeMemoryManager(1024)  # far too small for the 8 KB tensor
+    cache = DeviceImageCache()
+    cache.attach(mem)
+    assert cache.get_tensor(base, "w", raw.shape, np.float32) is None
+    assert mem.kind_bytes()["device_image"] == 0
+    mem.audit()
+
+
 # ------------------------------------------------- fused restore equality
 def test_fused_delta_restore_matches_eager(tmp_path):
     ps = 512
@@ -550,5 +585,175 @@ def test_residual_evict_rerestore_keeps_device_base(policy_zoo, tmp_path):
         assert after["misses"] == mid["misses"]
         np.testing.assert_array_equal(r1.tokens, r2.tokens)
         node.memory.audit()
+    finally:
+        node.close()
+
+
+# ------------------------------------- unchanged tensors share the HBM base
+PS = 512
+
+
+def _delta_restore(tmp_path, base_st, ft, *, node_base=None, memory=None):
+    """Restore the delta ``ft`` of ``base_st`` through a fresh DevicePath;
+    ``node_base`` (a state) stands in for the parent in the node cache,
+    ``memory`` is the ledger the device-image cache charges."""
+    from repro.core.lifecycle import parent_cache_key
+
+    parent = str(tmp_path / "p.jif")
+    delta = str(tmp_path / "d.jif")
+    snapshot(base_st, parent, page_size=PS)
+    snapshot(ft, delta, parent=parent, page_size=PS)
+    cache = NodeImageCache()
+    if node_base is not None:
+        cache.put(BaseImage.from_state(parent_cache_key(parent), node_base,
+                                       page_size=PS), evictable=False)
+    images = DeviceImageCache()
+    if memory is not None:
+        images.attach(memory)
+    up = UploadStream()
+    r = SpiceRestorer(node_cache=cache, device_path=DevicePath(upload=up, images=images))
+    try:
+        state, _, _, st = r.restore(delta, wait=True)
+        assert st.wait_complete(10)
+    finally:
+        r.iosched.shutdown()
+        up.close()
+    return state, st, up.snapshot_stats(), cache.get(parent_cache_key(parent)), images
+
+
+def _tensor_entries(images):
+    return [dev for key, (dev, _) in images._entries.items() if len(key) == 4]
+
+
+def test_unchanged_tensor_is_the_shared_base_array(tmp_path, monkeypatch):
+    from repro.kernels.overlay_patch import ops
+
+    calls = []
+    real = ops.overlay_patch_device
+
+    def counted(*a):
+        calls.append(a[2])
+        return real(*a)
+
+    monkeypatch.setattr(ops, "overlay_patch_device", counted)
+    rng = np.random.RandomState(7)
+    base_st = {k: rng.randn(4 * (PS // 4)).astype(np.float32) for k in "abc"}
+    ft = {k: v.copy() for k, v in base_st.items()}
+    ft["b"][: PS // 4] += 1.0  # mixed: one private page, three BASE
+    ft["c"] += 1.0  # every page private
+    state, st, ring, base, images = _delta_restore(tmp_path, base_st, ft)
+    for k in "abc":
+        np.testing.assert_array_equal(np.asarray(state[k]), ft[k], err_msg=k)
+    # the unchanged leaf IS the cache's array, and no other leaf is
+    assert state["a"] is images.get_tensor(base, "a", (PS,), np.float32)
+    assert not any(state[k] is dev for k in "bc" for dev in _tensor_entries(images))
+    # the ring carried only the mixed (fused) and the all-private tensors
+    assert ring["uploads"] == 2 and ring["fused_patches"] == 1
+    assert st.shared_tensors == 1 and st.shared_bytes == ft["a"].nbytes
+    assert st.as_dict()["shared_tensors"] == 1
+    assert st.patched_on_device_bytes == ft["b"].nbytes
+    assert st.uploaded_bytes == PS + ft["c"].nbytes
+    assert st.base_bytes == ft["a"].nbytes + 3 * PS
+    assert images.snapshot_stats()["base_bytes_served"] == st.base_bytes
+    assert len(calls) == 1  # the kernel still runs for the mixed tensor
+
+
+@pytest.mark.parametrize("case", ["private_page", "shape", "dtype", "length", "ledger"])
+def test_tensors_the_base_cannot_serve_take_the_ring(tmp_path, case):
+    """A tensor is shared only when every page is BASE and the base holds
+    it at the same shape, dtype and length, and the ledger admits it;
+    otherwise it restores on today's path, to the same leaf."""
+    raw = np.random.RandomState(3).randn(4 * (PS // 4)).astype(np.float32)
+    ft = {"w": raw.copy()}
+    node_base = memory = None
+    if case == "private_page":
+        ft["w"][-1] += 1.0
+    elif case == "shape":
+        node_base = {"w": raw.reshape(4, PS // 4)}
+    elif case == "dtype":
+        node_base = {"w": raw.view(np.int32)}
+    elif case == "length":
+        node_base = {"w": np.concatenate([raw, np.ones(PS // 4, np.float32)])}
+    else:
+        memory = NodeMemoryManager(1024)  # refuses every device entry
+    state, st, ring, _base, images = _delta_restore(
+        tmp_path, {"w": raw}, ft, node_base=node_base, memory=memory)
+    np.testing.assert_array_equal(np.asarray(state["w"]), ft["w"])
+    assert isinstance(state["w"], jax.Array)
+    assert st.shared_tensors == 0 and st.shared_bytes == 0
+    assert not any(state["w"] is dev for dev in _tensor_entries(images))
+    assert ring["uploads"] == 1
+    assert ring["fused_patches"] == (0 if case == "ledger" else 1)
+    if memory is not None:
+        memory.audit()
+
+
+def test_shared_base_arrays_outlive_instances_and_the_cache_entry(policy_zoo, tmp_path):
+    """On a fused node the unchanged leaves of a restored function are the
+    device-image cache's arrays: evicting the instance deletes none of
+    them, the reclaim rung dropping the entries leaves a live instance's
+    tree intact and the ledger balanced, and a second fine-tune of the
+    base reuses the entries instead of building them again."""
+    _d, cfg, params = policy_zoo
+    node = ServerlessNode(install="fused")
+    try:
+        base = BaseImage.from_state("pol-base", layerwise_state(cfg, params))
+        node.node_cache.put(base, evictable=False)
+        for i, fn in enumerate(("pol-fn", "pol-fn2")):
+            tuned = dict(params)
+            tuned["final_norm"] = tuned["final_norm"] + 0.01 * (i + 1)
+            node.publish(fn, cfg, tuned, str(tmp_path), base_name="pol-base",
+                         formats=("jif",), warm_ttl_s=60,
+                         extra_state={"opt": np.ones((1 << 16,), np.float32)})
+        images = node.scheduler.device_images
+        r = node.invoke("pol-fn", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+        assert r.cold
+        assert node.scheduler.drain_residual()
+        inst = node.scheduler.instance("pol-fn")
+        stats = inst.restore_stats
+        assert stats.shared_tensors > 0
+        entries = _tensor_entries(images)
+        shared = [a for a in jax.tree.leaves(inst.tree)
+                  if any(a is dev for dev in entries)]
+        assert len(shared) == stats.shared_tensors == len(entries)
+        host = [np.asarray(a) for a in shared]
+        warm = node.invoke("pol-fn", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+        assert not warm.cold
+        np.testing.assert_array_equal(r.tokens, warm.tokens)
+
+        # the rung drops the entries while the instance holds the arrays
+        mid = images.snapshot_stats()
+        assert images.reclaim(1 << 40) > 0 and images.resident_entries() == 0
+        node.memory.audit()
+        assert all(not a.is_deleted() for a in shared)
+        again = node.invoke("pol-fn", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+        assert not again.cold
+        np.testing.assert_array_equal(r.tokens, again.tokens)
+
+        # a second fine-tune's restore builds the entries once more, then a
+        # third restore of it hits them
+        r2 = node.invoke("pol-fn2", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+        assert r2.cold
+        assert node.scheduler.drain_residual()
+        built = images.snapshot_stats()
+        assert built["misses"] == mid["misses"] + stats.shared_tensors
+        node.evict("pol-fn2")
+        r3 = node.invoke("pol-fn2", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+        assert r3.cold
+        assert node.scheduler.drain_residual()
+        after = images.snapshot_stats()
+        assert after["tensor_hits"] == built["tensor_hits"] + stats.shared_tensors
+        assert after["misses"] == built["misses"]
+        np.testing.assert_array_equal(r2.tokens, r3.tokens)
+
+        # evicting the instances deletes no shared array
+        assert node.scheduler.evict_residual("pol-fn") > 0
+        node.evict()
+        node.memory.audit()
+        for a, h in zip(shared, host):
+            assert not a.is_deleted()
+            np.testing.assert_array_equal(np.asarray(a), h)
+        for dev in _tensor_entries(images):
+            assert not dev.is_deleted()
     finally:
         node.close()
