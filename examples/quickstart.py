@@ -25,21 +25,14 @@ def main():
         prompt = np.array([[11, 12, 13, 14]], dtype=np.int32)
 
         print("== warm up the compile cache (restored via keys, not re-trace)")
-        node.invoke("hello-fn", prompt, max_new_tokens=4, mode="spice_sync", cfg=cfg)
+        node.invoke("hello-fn", prompt, max_new_tokens=4, cfg=cfg)
         node.evict()
 
         print("== COLD start: restore from disk, overlap restore & execute")
-        r = node.invoke("hello-fn", prompt, max_new_tokens=8, mode="spice", cfg=cfg)
+        r = node.invoke("hello-fn", prompt, max_new_tokens=8, cfg=cfg)
         print(f"   tokens: {r.tokens[0].tolist()}")
         print(f"   ttft:   {r.ttft_s*1e3:.2f} ms   total: {r.total_s*1e3:.2f} ms")
         print(f"   restore stats: {r.stats}")
-
-        print("== baseline comparison (same function, CRIU*-style replay)")
-        node.evict()
-        rb = node.invoke("hello-fn", prompt, max_new_tokens=8, mode="criu_star", cfg=cfg)
-        assert np.array_equal(rb.tokens, r.tokens)
-        print(f"   criu*: total {rb.total_s*1e3:.2f} ms "
-              f"({rb.total_s/max(r.total_s,1e-9):.2f}x spice)")
 
 
 if __name__ == "__main__":

@@ -41,14 +41,13 @@ def main():
         cfgs = {"chat-a": base_cfg, "code-b": base_cfg, "ssm-c": ssm_cfg}
         # compile-cache warmup per arch
         for f, cfg in cfgs.items():
-            node.invoke(f, np.ones((1, 4), np.int32), 2, mode="spice_sync", cfg=cfg)
+            node.invoke(f, np.ones((1, 4), np.int32), 2, cfg=cfg)
 
         print(f"{'req':>3} {'function':>8} {'start':>6} {'ttft_ms':>9} {'total_ms':>9}")
         for i, (fname, plen) in enumerate(REQUESTS):
             node.evict()  # aggressive reclamation: idle instances are freed
             prompt = np.tile(np.arange(1, plen + 1, dtype=np.int32), (2, 1))
-            r = node.invoke(fname, prompt, max_new_tokens=4, mode="spice",
-                            cfg=cfgs[fname])
+            r = node.invoke(fname, prompt, max_new_tokens=4, cfg=cfgs[fname])
             print(f"{i:>3} {fname:>8} {'cold':>6} {r.ttft_s*1e3:9.2f} {r.total_s*1e3:9.2f}")
 
         print("\nnode cache:", node.node_cache.stats)

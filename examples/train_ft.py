@@ -81,7 +81,7 @@ def main():
               f"{deploy.lineage('assistant').canary_fraction:.0%} of traffic")
         prompt = np.array([[3, 1, 4, 1, 5, 9]], dtype=np.int32)
         served = [router.invoke("assistant", prompt, max_new_tokens=2,
-                                mode="spice", cfg=cfg).function
+                                cfg=cfg).function
                   for _ in range(6)]
         print(f"  A/B split served versions: {sorted(set(served))}")
         ok = deploy.evaluate_canary(

@@ -54,7 +54,7 @@ def criu_star_snapshot(state, dirpath: str) -> None:
     (d / "meta.json").write_text(json.dumps({"tree": tree, "index": index}))
 
 
-def criu_star_restore(dirpath: str, simulate_read_bw=None) -> Tuple[Any, BaselineStats]:
+def criu_star_restore(dirpath: str) -> Tuple[Any, BaselineStats]:
     stats = BaselineStats()
     t0 = time.perf_counter()
     d = Path(dirpath)
@@ -69,8 +69,6 @@ def criu_star_restore(dirpath: str, simulate_read_bw=None) -> Tuple[Any, Baselin
         stats.restore_ops += 3  # open / read / close
         stats.io_ops += 1
         stats.bytes_read += arr.nbytes
-        if simulate_read_bw:
-            time.sleep(arr.nbytes / simulate_read_bw)
         leaves[ent["name"]] = arr
     state = unflatten_state(meta["tree"], leaves)
     stats.total_s = time.perf_counter() - t0
@@ -115,7 +113,7 @@ class _MonolithReader:
         return os.pread(self.f.fileno(), nbytes, self.data_off + off)
 
 
-def reap_star_restore(path: str, simulate_read_bw=None) -> Tuple[Any, BaselineStats]:
+def reap_star_restore(path: str) -> Tuple[Any, BaselineStats]:
     """Synchronous prefetch of the ENTIRE image before execution."""
     stats = BaselineStats()
     t0 = time.perf_counter()
@@ -125,8 +123,6 @@ def reap_star_restore(path: str, simulate_read_bw=None) -> Tuple[Any, BaselineSt
     blob = r.read_span(0, total)  # one huge blocking read
     stats.io_ops += 1
     stats.bytes_read = len(blob)
-    if simulate_read_bw:
-        time.sleep(len(blob) / simulate_read_bw)
     leaves = {}
     for t in r.header["tensors"]:
         if t["name"].startswith("__extra__/"):
@@ -146,13 +142,12 @@ class FaasnapAsyncRestorer:
 
     FAULT_READ = 64 * 1024
 
-    def __init__(self, path: str, lag_s: float = 0.0, simulate_read_bw=None):
+    def __init__(self, path: str, lag_s: float = 0.0):
         self.stats = BaselineStats()
         self._t0 = time.perf_counter()
         self.r = _MonolithReader(path)
         self.stats.metadata_s = time.perf_counter() - self._t0
         self.lag_s = lag_s
-        self.simulate_read_bw = simulate_read_bw
         self._resident: Dict[str, np.ndarray] = {}
         self._lock = threading.Lock()
         self._tensors = [t for t in self.r.header["tensors"]]
@@ -175,8 +170,6 @@ class FaasnapAsyncRestorer:
             blob = self.r.read_span(t["off"], t["nbytes"])
             self.stats.io_ops += 1
             self.stats.bytes_read += len(blob)
-            if self.simulate_read_bw:
-                time.sleep(len(blob) / self.simulate_read_bw)
             with self._lock:
                 self._resident.setdefault(t["name"], self._materialize(t, blob))
 
@@ -194,9 +187,6 @@ class FaasnapAsyncRestorer:
             self.stats.io_ops += 1
             self.stats.bytes_read += nb
             self.stats.major_faults += 1
-            if self.simulate_read_bw:
-                # faults pay per-op latency on top of bandwidth
-                time.sleep(nb / self.simulate_read_bw + 20e-6)
         arr = self._materialize(t, b"".join(parts))
         with self._lock:
             self._resident.setdefault(name, arr)

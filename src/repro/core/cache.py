@@ -53,7 +53,6 @@ class BaseImage:
         name: Optional[str] = None,
         node_cache: Optional["NodeImageCache"] = None,
         iosched=None,
-        simulate_read_bw: Optional[float] = None,
         chunks=None,
     ) -> "BaseImage":
         """Materialize a full image from a JIF on disk.  The restore runs
@@ -70,16 +69,11 @@ class BaseImage:
             from repro.core.lifecycle import parent_cache_key
 
             name = parent_cache_key(path)
-        # pipelined even though we wait: inline streams are drained on the
-        # caller's thread and would bypass the scheduler's arbitration
         # ``chunks`` (the node's chunk cache) lets the bootstrap itself
         # dedup: a parent whose chunks a peer already pulled arrives over
         # the interconnect instead of the slow image store
-        restorer = SpiceRestorer(
-            node_cache=node_cache,
-            iosched=iosched, simulate_read_bw=simulate_read_bw,
-            chunks=chunks,
-        )
+        restorer = SpiceRestorer(node_cache=node_cache, iosched=iosched,
+                                 chunks=chunks)
         state, _, _, _ = restorer.restore(path)
         return cls.from_state(name, state, page_size)
 

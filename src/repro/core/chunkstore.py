@@ -50,10 +50,9 @@ class ChunkStore:
     drops to zero; :meth:`audit` asserts files-on-disk == refcounted set.
     """
 
-    def __init__(self, root: str, simulate_read_bw: Optional[float] = None):
+    def __init__(self, root: str):
         self.root = root
         os.makedirs(root, exist_ok=True)
-        self.simulate_read_bw = simulate_read_bw
         self._lock = threading.Lock()
         self._refs: Dict[bytes, int] = {}
         self.stats = {
@@ -134,9 +133,8 @@ class ChunkStore:
             return self._refs.get(digest_key(digest), 0)
 
     def get(self, digest) -> Optional[bytes]:
-        """Read one chunk's bytes (None when absent).  Applies the store's
-        simulated read bandwidth, mirroring how the image store's reads are
-        paced — a CAS hit is a LOCAL disk read, not free."""
+        """Read one chunk's bytes (None when absent): a CAS hit is a LOCAL
+        disk read, not free."""
         dk = digest_key(digest)
         with self._lock:
             if dk not in self._refs:
@@ -146,8 +144,6 @@ class ChunkStore:
                 data = f.read()
         except FileNotFoundError:
             return None
-        if self.simulate_read_bw:
-            time.sleep(len(data) / self.simulate_read_bw)
         with self._lock:
             self.stats["reads"] += 1
             self.stats["bytes_read"] += len(data)

@@ -154,13 +154,10 @@ class PrefetchIOScheduler:
         name: str,
         priority: int = 0,
         on_complete: Optional[Callable[[], None]] = None,
-        inline: bool = False,
         region=None,
         span_args: Optional[Dict] = None,
     ) -> IOStream:
-        """``inline`` streams are never served by the reader thread — the
-        caller drains them synchronously via :meth:`drain_inline`.
-        ``region`` (optional ledger region) receives in-flight I/O
+        """``region`` (optional ledger region) receives in-flight I/O
         accounting for every storage byte this stream reads.
         ``span_args`` (``function`` and ``req``) label the stream's spans."""
         stream = IOStream(self, name, priority=priority, on_complete=on_complete,
@@ -169,41 +166,15 @@ class PrefetchIOScheduler:
             if self._shutdown:
                 raise RuntimeError("scheduler is shut down")
             self.stats["streams_opened"] += 1
-            if not inline:
-                self._streams.append(stream)
-                if not self._running:
-                    self._running = True
-                    self._thread = threading.Thread(
-                        target=self._loop, name=f"{self.name}-reader", daemon=True
-                    )
-                    self._thread.start()
+            self._streams.append(stream)
+            if not self._running:
+                self._running = True
+                self._thread = threading.Thread(
+                    target=self._loop, name=f"{self.name}-reader", daemon=True
+                )
+                self._thread.start()
             self._cv.notify_all()
         return stream
-
-    def drain_inline(self, stream: IOStream) -> None:
-        """Execute a stream synchronously on the caller's thread (the
-        non-pipelined restore path); the stream must be sealed."""
-        while True:
-            with self._cv:
-                if not stream._jobs:
-                    break
-                job = stream._jobs[0]
-                op = job.ops.popleft() if job.ops else None
-                if op is None:
-                    stream._jobs.popleft()
-                    stream._by_name.pop(job.name, None)
-            try:
-                if op is not None:
-                    self._run_op(stream, op)
-                elif job.finalize is not None:
-                    job.finalize()
-                    with self._cv:
-                        stream.stats["tensors"] += 1
-                        self.stats["tensors"] += 1
-            except BaseException as exc:  # noqa: BLE001
-                self._fail_stream(stream, exc)
-                raise
-        self._maybe_complete(stream)
 
     # -------------------------------------------------------------- boost
     def _boost(self, stream: IOStream, tensor_name: str) -> bool:
@@ -350,9 +321,7 @@ class PrefetchIOScheduler:
         """Live load probe for placement: the number of registered
         (uncompleted) streams and an estimate of the bytes still to land
         across them (``region.nbytes - region.filled`` for streams that
-        carry a ledger region; region-less streams count bytes as 0).
-        Inline streams never register here, so this is exactly the work
-        queued against the reader thread."""
+        carry a ledger region; region-less streams count bytes as 0)."""
         with self._cv:
             streams = [s for s in self._streams if not s._completed]
             pending = 0

@@ -135,7 +135,7 @@ class BufferPool:
 
     def _sweep_locked(self) -> None:
         """Reclaim charges of outstanding buffers that were GC'd without a
-        release (e.g. a non-pipelined restore whose state tree was dropped)."""
+        release (e.g. a restore whose state tree was dropped)."""
         dead = [k for k, (ref, _sc, _c) in self._outstanding.items() if ref() is None]
         for key in dead:
             _, sc, charged = self._outstanding.pop(key)

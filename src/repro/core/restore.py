@@ -231,9 +231,7 @@ class SpiceRestorer:
         pool: Optional[BufferPool] = None,
         node_cache: Optional[NodeImageCache] = None,
         io_chunk_bytes: int = 8 << 20,
-        pipelined: bool = True,
         transform: Optional[Callable[[np.ndarray], Any]] = None,
-        simulate_read_bw: Optional[float] = None,
         iosched: Optional[PrefetchIOScheduler] = None,
         stream_priority: int = 0,
         memory: Optional[NodeMemoryManager] = None,
@@ -243,9 +241,7 @@ class SpiceRestorer:
     ):
         """``transform`` runs on the scheduler's reader thread per completed
         tensor (e.g. jnp.asarray = eager device install, off the critical
-        path).  ``simulate_read_bw`` (bytes/s) sleeps during reads to model
-        real storage latency when files are page-cache resident (labeled
-        runs only).  ``iosched`` is the node-shared prefetch scheduler; when
+        path).  ``iosched`` is the node-shared prefetch scheduler; when
         omitted a private one is created per restorer (standalone use).
         ``memory`` is the node ledger: when given, a restore reserves its
         working-set and residual regions up front — a restore that cannot
@@ -284,9 +280,7 @@ class SpiceRestorer:
         self.pool = pool or BufferPool()
         self.node_cache = node_cache or NodeImageCache()
         self.io_chunk_bytes = io_chunk_bytes
-        self.pipelined = pipelined
         self.transform = transform
-        self.simulate_read_bw = simulate_read_bw
         self.iosched = iosched or PrefetchIOScheduler(name="spice-private")
         self.stream_priority = stream_priority
         self.memory = memory
@@ -598,8 +592,6 @@ class SpiceRestorer:
             t = r.by_name[name]
             ps = r.page_size
             raw = r.pread_chunks(src, count)
-            if self.simulate_read_bw:
-                time.sleep(len(raw) / self.simulate_read_bw)
             dst0 = dst_chunk * ps
             nb = min(len(raw), t.nbytes - dst0)
             buffers[name][dst0 : dst0 + nb] = np.frombuffer(raw[:nb], np.uint8)
@@ -626,8 +618,6 @@ class SpiceRestorer:
 
             def pull(j0: int, n: int) -> None:
                 raw = r.pread_chunks(src + j0, n)
-                if self.simulate_read_bw:
-                    time.sleep(len(raw) / self.simulate_read_bw)
                 dst0 = (dst_chunk + j0) * ps
                 nb = min(len(raw), t.nbytes - dst0)
                 buffers[name][dst0 : dst0 + nb] = np.frombuffer(raw[:nb], np.uint8)
@@ -676,8 +666,6 @@ class SpiceRestorer:
             not tensor pages — the fused tensor never exists on host."""
             ps = r.page_size
             raw = r.pread_chunks(src, count)
-            if self.simulate_read_bw:
-                time.sleep(len(raw) / self.simulate_read_bw)
             dst0 = dst_slot * ps
             buffers[name][dst0 : dst0 + len(raw)] = np.frombuffer(raw, np.uint8)
             stats.add(bytes_read=len(raw), io_ops=1)
@@ -734,7 +722,6 @@ class SpiceRestorer:
             stream = self.iosched.open_stream(
                 name=os.path.basename(path),
                 priority=self.stream_priority,
-                inline=not self.pipelined,
                 region=region_ws,
                 span_args=self.span_args,
             )
@@ -786,10 +773,7 @@ class SpiceRestorer:
         for name, h in handles.items():
             h.attach_demand(partial(self._boost, stream, stats, name))
 
-        if not self.pipelined:
-            # synchronous path: drain on the caller's thread (no overlap)
-            self.iosched.drain_inline(stream)
-        elif wait:
+        if wait:
             stream.wait()
 
         leaves: Dict[str, Any] = {name: handles[name] for name in handles}
@@ -901,7 +885,6 @@ class SpiceRestorer:
                                 ref["path"], name=name,
                                 node_cache=self.node_cache,
                                 iosched=self.iosched,
-                                simulate_read_bw=self.simulate_read_bw,
                                 chunks=self.chunks,
                             )
                         except FileNotFoundError:

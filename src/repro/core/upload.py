@@ -148,18 +148,10 @@ class UploadStream:
     failed upload never hangs a waiter."""
 
     def __init__(self, depth: int = UPLOAD_DEPTH, name: str = "upload-stream",
-                 install: Optional[Callable] = None,
-                 simulate_bw: Optional[float] = None):
-        """``simulate_bw`` (bytes/s) models the host→device interconnect
-        roofline the same way ``simulate_read_bw`` models storage: the
-        issuer sleeps for the bytes each job actually moves (private pages
-        only for fused jobs — the fast path's economy shows up as shorter
-        sleeps), so the simulated link moves one job's bytes at a time.
-        Labeled benchmark runs only; None on real hardware."""
+                 install: Optional[Callable] = None):
         self.name = name
         self.depth = max(1, int(depth))
         self.install = install or _default_install
-        self.simulate_bw = simulate_bw
         self._issue_q: "queue.Queue" = queue.Queue()
         self._land_q: "queue.Queue" = queue.Queue()
         self._cv = threading.Condition(threading.Lock())
@@ -223,8 +215,6 @@ class UploadStream:
         job.t_issue = time.perf_counter()
         with span("spice.upload.issue", **job.span_args):
             try:
-                if self.simulate_bw:
-                    time.sleep(job.uploaded / self.simulate_bw)
                 job.issue(job)
             except BaseException as exc:  # noqa: BLE001 — typed via handle
                 self._fail(job, exc)
@@ -600,13 +590,8 @@ class DeviceImageCache:
 @dataclasses.dataclass
 class DevicePath:
     """The device-restore bundle a :class:`SpiceRestorer` takes as its
-    ``device_path=`` mode: the node's shared upload ring, the HBM base
-    cache (None disables fused patching — every tensor full-uploads), and
-    the host→device install transform."""
+    ``device_path=`` mode: the node's shared upload ring and the HBM base
+    cache (None disables fused patching — every tensor full-uploads)."""
 
     upload: UploadStream
     images: Optional[DeviceImageCache] = None
-    install: Optional[Callable] = None
-
-    def installer(self) -> Callable:
-        return self.install or _default_install

@@ -3,18 +3,16 @@ it, then serve requests of the fine-tune with cold restores (the Spice
 serving loop).
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
-      --requests 8 --mode spice [--keep-warm | --prewarm] [--full-config]
+      --requests 8 [--keep-warm | --prewarm] [--full-config]
 
 Uses the reduced config by default (CPU); ``--full-config`` serves the
 published widths, which needs an accelerator.  The node's memory ledger is
 sized from the bytes of the published image, so the full widths fit.
 
 What is served is a delta fine-tune of a base pinned in the node's image
-cache, as on a platform that hosts many fine-tunes of a few bases.  The
-``spice`` modes therefore restore mostly BASE pages from the node cache
-and read only the fine-tune's private pages from storage, while
-``criu_star``, ``reap_star`` and ``faasnap_star`` restore the same weights
-from their own full snapshot images, which share nothing with the base.
+cache, as on a platform that hosts many fine-tunes of a few bases.  A cold
+restore therefore serves mostly BASE pages from the node cache and reads
+only the fine-tune's private pages from storage.
 
 Warmth modes:
   (none)       every request is a cold start (no keep-alive)
@@ -31,7 +29,7 @@ from __future__ import annotations
 import argparse
 import tempfile
 import time
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -124,14 +122,13 @@ def install_base(node: ServerlessNode, cfg: ModelConfig, params,
 
 
 def publish_functions(node: ServerlessNode, dirpath: str, cfg: ModelConfig,
-                      params, formats: Tuple[str, ...] = ("jif",)) -> Dict:
+                      params) -> Dict:
     """Publish the base and its delta fine-tune against the cached base
     image; returns ``{function: params}`` for what each one serves."""
     published = {BASE_FN: params,
                  TUNED_FN: finetune(cfg, params, FINETUNE_SCALE)}
     for fname, p in published.items():
-        node.publish(fname, cfg, p, dirpath, base_name=BASE_IMAGE,
-                     formats=formats)
+        node.publish(fname, cfg, p, dirpath, base_name=BASE_IMAGE)
     return published
 
 
@@ -142,9 +139,6 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=8)
-    ap.add_argument("--mode", default="spice",
-                    choices=["spice", "spice_sync", "criu_star", "reap_star",
-                             "faasnap_star"])
     ap.add_argument("--full-config", action="store_true",
                     help="serve the published widths (needs an accelerator)")
     ap.add_argument("--interval", type=float, default=0.0,
@@ -182,16 +176,12 @@ def main() -> None:
     node = serving_node("eager", nbytes, keep_warm=args.keep_warm, **kw)
     with tempfile.TemporaryDirectory() as d:
         install_base(node, cfg, params)
-        # the baseline modes restore from their own snapshot formats
-        formats = ("jif",) if args.mode.startswith("spice") else (
-            "jif", "criu", "monolith")
-        publish_functions(node, d, cfg, params, formats=formats)
+        publish_functions(node, d, cfg, params)
         prompt = np.tile(np.arange(1, args.prompt_len + 1, dtype=np.int32),
                          (args.batch, 1))
         # compile-cache warmup
         node.submit_invocation(Invocation(
-            function=TUNED_FN, prompt=prompt, max_new_tokens=2,
-            mode="spice_sync", cfg=cfg,
+            function=TUNED_FN, prompt=prompt, max_new_tokens=2, cfg=cfg,
         )).result()
         node.evict()
 
@@ -201,9 +191,9 @@ def main() -> None:
                 node.evict()
             r = node.submit_invocation(Invocation(
                 function=TUNED_FN, prompt=prompt, max_new_tokens=args.max_new,
-                mode=args.mode, cfg=cfg,
+                cfg=cfg,
             )).result()
-            path = "warm" if not r.cold else ("join" if r.joined else args.mode)
+            path = "warm" if not r.cold else ("join" if r.joined else "cold")
             print(f"{i:>4} {path:>6} "
                   f"{r.ttft_s*1e3:9.2f} {r.total_s*1e3:9.2f}")
             if args.interval:

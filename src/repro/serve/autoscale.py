@@ -1,9 +1,9 @@
 """SLO-driven elastic autoscaling with warm-state handoff on scale-in.
 
-The static ``scale_out_queue_depth`` knob sizes a fleet for its worst
-minute: a queue-depth threshold neither knows what latency the user
-actually experiences nor ever gives a node back.  This module closes the
-loop against *declared service objectives* instead:
+A static queue-depth threshold sizes a fleet for its worst minute: it
+neither knows what latency the user actually experiences nor ever gives a
+node back.  This module closes the loop against *declared service
+objectives* instead:
 
 * :class:`ServiceSLO` — per-QoS-class targets (TTFT p99, queue-wait p95),
   the contract the operator writes down.
@@ -23,9 +23,9 @@ loop against *declared service objectives* instead:
   3. release the node's residual stream and ledger (audit-clean) and
      remove it from the fleet.
 
-``tick()`` is a plain method: benchmarks call it from the replay loop for
-determinism, deployments run :meth:`AutoScaler.start` for a daemon-thread
-loop (weakref'd like the node reaper — a dropped fleet is GC-able).
+``tick()`` is a plain method: tests call it directly for determinism,
+deployments run :meth:`AutoScaler.start` for a daemon-thread loop
+(weakref'd like the node reaper — a dropped fleet is GC-able).
 """
 from __future__ import annotations
 
@@ -153,8 +153,8 @@ class AutoScaler:
     (with warm-state handoff) on sustained slack.
 
     ``node_factory(name) -> NodeScheduler`` provisions a node when the
-    loop scales out (the benchmark builds one with the fleet's chunk
-    cache/ledger shape; a deployment would boot a machine).  ``keepalive``
+    loop scales out (a test builds one with the fleet's chunk cache and
+    ledger shape; a deployment would boot a machine).  ``keepalive``
     (a :class:`~repro.serve.prewarm.PrewarmPolicy`) ranks a draining
     node's warm instances by re-restore cost / predicted demand; handoffs
     run most-valuable-first so, if the drain budget runs out, what is
@@ -162,7 +162,7 @@ class AutoScaler:
     drain-and-evict ablation: scale-in simply evicts warm state, and the
     next request for each function pays a full cold restore.
 
-    Node-seconds (the cost metric benchmarks compare) accrue per node from
+    Node-seconds (the fleet's cost metric) accrue per node from
     join (or :meth:`attach`) to removal."""
 
     def __init__(
@@ -181,7 +181,6 @@ class AutoScaler:
         slack_margin: float = 0.5,
         handoff: bool = True,
         drain_timeout_s: float = 30.0,
-        simulate_read_bw: Optional[float] = None,
     ):
         self.router = router
         self.slos = list(slos)
@@ -196,7 +195,6 @@ class AutoScaler:
         self.slack_margin = slack_margin
         self.handoff = handoff
         self.drain_timeout_s = drain_timeout_s
-        self.simulate_read_bw = simulate_read_bw
         self._lock = threading.Lock()
         self._violating_ticks = 0
         self._slack_ticks = 0
@@ -244,7 +242,7 @@ class AutoScaler:
     # ----------------------------------------------------------- the loop
     def tick(self, now: Optional[float] = None) -> Optional[str]:
         """One control decision; returns "scale_out"/"scale_in"/None.
-        Callable inline (deterministic benchmarks) or from the daemon
+        Callable inline (deterministic tests) or from the daemon
         thread (:meth:`start`)."""
         self.stats["ticks"] += 1
         now = time.monotonic() if now is None else now
@@ -346,7 +344,6 @@ class AutoScaler:
                         self.router, fname, name, dst.name,
                         handoff_dir=self.handoff_dir,
                         timeout=self.drain_timeout_s,
-                        simulate_read_bw=self.simulate_read_bw,
                     )
                     self.handoffs.append(hs)
                     if hs.ok:

@@ -33,13 +33,9 @@ Routing contract:
   least-loaded: a node that holds the function's base image (or the parent
   of its delta chain) restores it reading only private chunks, which is
   the whole point of disk-resident snapshots.
-* **Scale-out knob** — with ``scale_out_queue_depth=K``, a function whose
-  least-loaded replica has K or more invocations in flight gets a second
-  replica placed by the same policy (opt-in; capped at the node count).
 
 ``RoundRobin`` and ``LeastLoaded`` are non-sticky baselines: they place
-every request independently, which is exactly the placement regime the
-cluster benchmark (``benchmarks/cluster.py``) compares against.
+every request independently.
 """
 from __future__ import annotations
 
@@ -194,7 +190,7 @@ class FunctionCatalog:
         base_name: Optional[str] = None,
         parent: Optional[str] = None,
         warm_ttl_s: float = 0.0,
-        formats: Tuple[str, ...] = ("jif", "criu", "monolith"),
+        formats: Tuple[str, ...] = ("jif",),
         extra_state: Optional[Any] = None,
         memory: Optional[NodeMemoryManager] = None,
     ) -> FunctionSpec:
@@ -202,9 +198,11 @@ class FunctionCatalog:
         access-order relocation, dedup vs an in-memory base (``base_name``)
         or a parent JIF on disk (``parent`` — delta publishing: any node
         can bootstrap the parent from the snapshot store, no pre-installed
-        base required); also writes the baselines' formats for comparison.
-        ``memory`` (a node's ledger) charges the writer's state copy as
-        scratch so publishing competes with live tenants honestly."""
+        base required).  ``formats`` may add the baselines' snapshot
+        formats (``"criu"``, ``"monolith"``; :mod:`repro.core.baselines`)
+        beside the JIF, as references.  ``memory`` (a node's ledger)
+        charges the writer's state copy as scratch so publishing competes
+        with live tenants honestly."""
         if base_name is not None and parent is not None:
             raise ValueError("pass either base_name= or parent=, not both")
         os.makedirs(dirpath, exist_ok=True)
@@ -372,7 +370,6 @@ class FunctionCatalog:
                 node_cache=(
                     node.node_cache if node is not None else self.base_images
                 ),
-                pipelined=False,
                 iosched=node.iosched if node is not None else None,
             )
             state, _, _, _ = restorer.restore(spec.jif_path)
@@ -599,10 +596,8 @@ class ClusterRouter:
         catalog: FunctionCatalog,
         nodes: Sequence[NodeScheduler],
         placement: Optional[PlacementPolicy] = None,
-        scale_out_queue_depth: Optional[int] = None,
         latency_spill_depth: int = 2,
         urgent_deadline_s: float = 1.0,
-        interconnect_bw: Optional[float] = None,
         prewarm=None,
         load_cache_ttl_s: float = 0.005,
     ):
@@ -610,13 +605,9 @@ class ClusterRouter:
         a deadline within ``urgent_deadline_s``) whose sticky replica has
         this many invocations in flight steals a replica on the node
         ``place_urgent`` picks instead of queueing — BATCH work waits where
-        LATENCY work scales out.
-
-        ``scale_out_queue_depth`` is DEPRECATED as a scaling mechanism: it
-        is a static per-function replica-growth threshold, kept as an alias
-        for existing callers.  New deployments should drive replica and
-        node count through :class:`repro.serve.autoscale.AutoScaler`, which
-        reacts to declared SLOs instead of a fixed queue depth.
+        LATENCY work scales out.  Replica and node count beyond that are
+        driven by :class:`repro.serve.autoscale.AutoScaler`, which reacts to
+        declared SLOs.
 
         ``load_cache_ttl_s`` bounds the cost of placement probes at fleet
         scale: the router sets it as every node's ``load_ttl_s``, so a
@@ -625,11 +616,6 @@ class ClusterRouter:
         the TTL *and* by instance lifecycle edges (any state transition
         invalidates that node's cached probe immediately); 0 disables
         caching.
-
-        ``interconnect_bw`` (bytes/s) paces peer chunk transfers between
-        nodes with chunk caches, modeling the node-to-node fabric the same
-        way ``simulate_read_bw``/``simulate_upload_bw`` model storage and
-        PCIe (labeled benchmark runs only; None = instantaneous).
 
         ``prewarm`` (a :class:`repro.serve.prewarm.PrewarmEngine`) turns
         on predictive warmth management: every real ``submit_invocation``
@@ -655,10 +641,8 @@ class ClusterRouter:
         if len(set(names)) != len(names):
             raise ValueError(f"node names must be unique, got {names}")
         self.placement = placement or LocalityFirst()
-        self.scale_out_queue_depth = scale_out_queue_depth
         self.latency_spill_depth = latency_spill_depth
         self.urgent_deadline_s = urgent_deadline_s
-        self.interconnect_bw = interconnect_bw
         self.load_cache_ttl_s = load_cache_ttl_s
         self._lock = threading.Lock()
         self._closed = False
@@ -669,7 +653,6 @@ class ClusterRouter:
         self._chunk_caches: Dict[str, Any] = {}
         self.stats = {
             "routed": 0,
-            "scale_outs": 0,
             "latency_steals": 0,
             "peer_fetches": 0,
             "peer_fetch_bytes": 0,
@@ -691,11 +674,9 @@ class ClusterRouter:
         """Connect one node's chunk cache to the cluster: residency
         announcements feed the catalog's digest→holders index, and the
         peer-fetch hook pulls a missing chunk from whichever peer holds it
-        (paced by ``interconnect_bw``) instead of re-reading the image
-        store.  The peer serves via ``peek`` — RAM first, else its local
-        CAS file — so a transfer never perturbs the holder's LRU."""
-        import time as _time
-
+        instead of re-reading the image store.  The peer serves via
+        ``peek`` — RAM first, else its local CAS file — so a transfer never
+        perturbs the holder's LRU."""
         if node.chunks is None:
             return
         cache = node.chunks
@@ -712,8 +693,6 @@ class ClusterRouter:
                 data = peer.peek(digest)
                 if data is None:
                     continue  # stale index entry: try the next holder
-                if self.interconnect_bw:
-                    _time.sleep(len(data) / self.interconnect_bw)
                 with self._lock:
                     self.stats["peer_fetches"] += 1
                     self.stats["peer_fetch_bytes"] += len(data)
@@ -861,36 +840,21 @@ class ClusterRouter:
             # discounts parked BATCH occupancy) and this invocation cannot
             # wait — grow a replica where place_urgent points (a BATCH
             # invocation queues instead)
-            return self._grow_replica(
-                fname, spec, key, live, by_name[best], urgent=True
-            )
-        if (
-            self.scale_out_queue_depth is not None
-            and (inv is None or inv.qos is not QosClass.BATCH)
-            and room
-            and loads[best].queue_depth >= self.scale_out_queue_depth
-        ):
-            # opt-in scale-out: the least-loaded replica is still backed
-            # up — place one more replica by the same policy.  BATCH-class
-            # invocations never trigger it: background work waits.
-            return self._grow_replica(
-                fname, spec, key, live, by_name[best], urgent=False
-            )
+            return self._grow_replica(fname, spec, key, live, by_name[best])
         return by_name[best]
 
     def _grow_replica(
-        self, fname, spec, key, live, best: NodeScheduler, urgent
+        self, fname, spec, key, live, best: NodeScheduler
     ) -> NodeScheduler:
         rest = [n for n in self.active_nodes() if n.name not in live]
         if not rest:
             return best
-        place = self.placement.place_urgent if urgent else self.placement.place
-        new = rest[place(spec, key, self._probe(rest))]
+        new = rest[self.placement.place_urgent(spec, key, self._probe(rest))]
         with self._lock:
             current = self._assign.setdefault(fname, [best.name])
             if new.name not in current:
                 current.append(new.name)
-                self.stats["latency_steals" if urgent else "scale_outs"] += 1
+                self.stats["latency_steals"] += 1
                 return new
         return best
 
@@ -928,14 +892,12 @@ class ClusterRouter:
         fname: str,
         prompt: np.ndarray,
         max_new_tokens: int = 8,
-        mode: str = "spice",
         cfg: Optional[ModelConfig] = None,
-        simulate_read_bw: Optional[float] = None,
     ) -> InvocationHandle:
         """Legacy surface: a STANDARD-class :class:`Invocation` wrapper."""
         return self.submit_invocation(Invocation(
             function=fname, prompt=prompt, max_new_tokens=max_new_tokens,
-            mode=mode, cfg=cfg, simulate_read_bw=simulate_read_bw,
+            cfg=cfg,
         ))
 
     def invoke(self, *args, **kwargs) -> InvokeResult:

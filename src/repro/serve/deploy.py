@@ -33,7 +33,7 @@ restores through the same near-warm path as any other function.
 The full loop — ``CheckpointManager.save`` →
 :class:`repro.ft.publish.DeltaPublishCallback` → ``publish_version`` →
 ``begin_canary`` → ``evaluate_canary`` → promote/rollback — is exercised
-end-to-end by ``benchmarks/rollout.py``.
+end-to-end by ``examples/train_ft.py``.
 """
 from __future__ import annotations
 

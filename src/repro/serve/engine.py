@@ -13,7 +13,7 @@ The monolithic ``ServerlessNode`` was split into a layered runtime:
   with pluggable snapshot-locality-aware placement.
 
 ``ServerlessNode`` here is a thin facade composing a catalog with a
-one-node router, so the existing examples, benchmarks, and tests keep
+one-node router, so the existing examples and tests keep
 their `publish`/`invoke`/`evict` surface; new code should target the
 layers directly.
 """
@@ -149,7 +149,7 @@ class ServerlessNode:
             self._catalog, [self._sched], prewarm=prewarm
         )
 
-    # shared-component accessors (benchmarks swap the pool between runs)
+    # shared-component accessors
     @property
     def scheduler(self) -> NodeScheduler:
         return self._sched
@@ -182,13 +182,6 @@ class ServerlessNode:
     @property
     def pool(self) -> BufferPool:
         return self._sched.pool
-
-    @pool.setter
-    def pool(self, new_pool: BufferPool) -> None:
-        self._sched.pool = new_pool
-        # a zero-capacity pool means "no pooling", not "no memory": leave
-        # the ledger unlimited rather than refusing every restore
-        self._sched.memory_budget = new_pool.capacity or None
 
     def publish(self, *args, **kwargs):
         # the writer's state copy is node memory too: charge it as scratch

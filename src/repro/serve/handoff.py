@@ -26,7 +26,7 @@ The destination reads the delta's private chunks plus whatever base chunks
 it does not already hold — and because the base image was published into
 the cluster CAS, those are peer-fetchable rather than re-read from the
 image store.  ``HandoffStats.delta_bytes`` vs ``restore_read_bytes`` is
-exactly the wire saving the scale benchmark asserts on.
+exactly that wire saving.
 """
 from __future__ import annotations
 
@@ -107,7 +107,6 @@ def handoff_warm(
     handoff_dir: str,
     cfg: Optional[ModelConfig] = None,
     timeout: float = 60.0,
-    simulate_read_bw: Optional[float] = None,
     qos: QosClass = QosClass.STANDARD,
     evict_source: bool = True,
     retire: bool = True,
@@ -162,7 +161,6 @@ def handoff_warm(
             qos=qos,
             prewarm=True,  # restore+promote, skip generation; accounted a
             # speculative_restore — a handoff is never a demand cold start
-            simulate_read_bw=simulate_read_bw,
             jif_override=path,
         ))
         result = handle.result(timeout=timeout)

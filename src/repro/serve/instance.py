@@ -192,23 +192,6 @@ def generate(cfg, getter, state, prompt: np.ndarray, max_new: int,
     return np.stack(out, axis=1), ttft
 
 
-class _FaasnapLeaf:
-    def __init__(self, r, name):
-        self._r = r
-        self.name = name
-
-    def fault(self):
-        return self._r.ensure(self.name)
-
-
-def faasnap_wait(tree):
-    return jax.tree.map(
-        lambda l: jnp.asarray(l.fault()) if isinstance(l, _FaasnapLeaf) else l,
-        tree,
-        is_leaf=lambda l: isinstance(l, _FaasnapLeaf),
-    )
-
-
 class NotWarmError(RuntimeError):
     """The instance was not WARM when a warm-tree pin was requested —
     distinct from RuntimeErrors raised by work done *under* the pin, so
@@ -247,7 +230,6 @@ class FunctionInstance:
         self.tree: Optional[Any] = None          # handles while RESTORING,
         self.getter: Optional[Callable] = None   # resolved arrays once WARM
         self.restore_stats: Optional[RestoreStats] = None
-        self.restore_mode: Optional[str] = None
         self.last_used = 0.0
         self.warm_expiry = 0.0   # 0 = no keep-alive
         self.memory_bytes = 0
@@ -350,11 +332,10 @@ class FunctionInstance:
         self.ws_region = ws_region
         self.residual_region = residual_region
 
-    def begin_restore(self, mode: str) -> int:
+    def begin_restore(self) -> int:
         assert self.state in (InstanceState.COLD, InstanceState.EVICTED), self.state
         self.state = InstanceState.RESTORING
         self.generation += 1
-        self.restore_mode = mode
         self.tree = None
         self.getter = None
         self.ws_ready = False

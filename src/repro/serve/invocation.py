@@ -133,9 +133,7 @@ class Invocation:
     function: str
     prompt: Any = None
     max_new_tokens: int = 8
-    mode: str = "spice"
     cfg: Any = None
-    simulate_read_bw: Optional[float] = None
     qos: QosClass = QosClass.STANDARD
     deadline_s: Optional[float] = None
     priority: int = 0

@@ -51,12 +51,7 @@ from repro.core import (
     PrefetchIOScheduler,
     SpiceRestorer,
 )
-from repro.core import baselines
-from repro.core.memory import (
-    KIND_WORKING_SET,
-    MemoryPressureError,
-    NodeMemoryManager,
-)
+from repro.core.memory import NodeMemoryManager
 from repro.core.restore import RestoreStats, estimate_rerestore_cost
 from repro.core.spans import span
 from repro.core.trace import AccessRecorder
@@ -80,14 +75,10 @@ from repro.serve.invocation import (
     Overloaded,
     QosClass,
 )
-from repro.core.treeutil import unflatten_state
 from repro.serve.instance import (
     FunctionInstance,
     InstanceState,
     NotWarmError,
-    _FaasnapLeaf,
-    _tree_bytes as _tree_nbytes,
-    faasnap_wait,
     generate,
     wait_tree,
 )
@@ -108,7 +99,7 @@ class InvokeResult:
     node: str = ""  # serving node's name ("" on single-node paths)
     qos: str = "standard"  # QosClass.value of the request
     # derived from the handle's event timeline (time.monotonic() domain):
-    # queue_wait_s splits queueing delay from restore delay in benchmarks
+    # queue_wait_s splits queueing delay from restore delay
     queue_wait_s: float = 0.0   # ADMITTED -> first work on the request
     admitted_ts: float = 0.0    # monotonic timestamps of the named events
     placed_ts: float = 0.0
@@ -238,7 +229,6 @@ class NodeScheduler:
         admission: Optional[AdmissionController] = None,
         install: object = "eager",
         upload_depth: int = UPLOAD_DEPTH,
-        simulate_upload_bw: Optional[float] = None,
         chunks: Optional[NodeChunkCache] = None,
         load_ttl_s: float = 0.0,
     ):
@@ -249,9 +239,7 @@ class NodeScheduler:
         upload and overlay-patch against HBM-resident bases), or a callable
         (custom per-tensor transform, eager-style).  ``upload_depth`` bounds
         the fused path's upload ring: jobs submitted and not yet landed on
-        the device, queued and in flight together;
-        ``simulate_upload_bw`` models the interconnect roofline on the ring
-        (labeled benchmark runs only, like ``simulate_read_bw``).
+        the device, queued and in flight together.
         ``chunks`` (a :class:`repro.core.chunkstore.NodeChunkCache` over
         the cluster's shared CAS) enables content-addressed dedup on every
         spice restore this node runs; its RAM tier attaches to the ledger
@@ -295,7 +283,6 @@ class NodeScheduler:
             # cap just tracks the node budget.
             self.upload_stream = UploadStream(
                 depth=upload_depth, name=f"{name or 'node'}-upload",
-                simulate_bw=simulate_upload_bw,
             )
             self.device_images = DeviceImageCache(
                 capacity_bytes=budget if budget else 4 << 30
@@ -309,7 +296,7 @@ class NodeScheduler:
         # without this rung the free list's charge would ratchet up
         # unreclaimably), then LRU warm instances
         self.memory.register_reclaimer("residual", self._reclaim_residual, order=0)
-        self.memory.register_reclaimer("pool", self._reclaim_pool, order=4)
+        self.memory.register_reclaimer("pool", self._pool.reclaim, order=4)
         self.memory.register_reclaimer("warm-lru", self._reclaim_warm_lru, order=5)
         self._instances: Dict[str, FunctionInstance] = {}
         self._ilock = threading.Lock()
@@ -375,15 +362,6 @@ class NodeScheduler:
     @property
     def pool(self) -> BufferPool:
         return self._pool
-
-    @pool.setter
-    def pool(self, new_pool: BufferPool) -> None:
-        """Swap the staging pool (benchmarks do this between runs): the old
-        pool's ledger charge is released, the new pool is attached."""
-        old, self._pool = self._pool, new_pool
-        if old is not None and old is not new_pool:
-            old.detach()
-        new_pool.attach(self.memory)
 
     @property
     def memory_budget(self) -> Optional[int]:
@@ -462,16 +440,14 @@ class NodeScheduler:
         fname: str,
         prompt: np.ndarray,
         max_new_tokens: int = 8,
-        mode: str = "spice",
         cfg: Optional[ModelConfig] = None,
-        simulate_read_bw: Optional[float] = None,
     ) -> InvocationHandle:
         """Legacy surface: a thin wrapper building a STANDARD-class
         :class:`Invocation` (the returned handle duck-types the Future the
         old surface handed back)."""
         return self.submit_invocation(Invocation(
             function=fname, prompt=prompt, max_new_tokens=max_new_tokens,
-            mode=mode, cfg=cfg, simulate_read_bw=simulate_read_bw,
+            cfg=cfg,
         ))
 
     def invoke(
@@ -479,13 +455,9 @@ class NodeScheduler:
         fname: str,
         prompt: np.ndarray,
         max_new_tokens: int = 8,
-        mode: str = "spice",
         cfg: Optional[ModelConfig] = None,
-        simulate_read_bw: Optional[float] = None,
     ) -> InvokeResult:
-        return self.submit(
-            fname, prompt, max_new_tokens, mode, cfg, simulate_read_bw
-        ).result()
+        return self.submit(fname, prompt, max_new_tokens, cfg).result()
 
     def _retire(self, handle: InvocationHandle) -> None:
         """Return one admitted invocation's counters (dispatch done, or the
@@ -647,9 +619,9 @@ class NodeScheduler:
         """Enforce keep-alive TTLs periodically on a daemon thread, so an
         idle node releases expired warm instances' ledger bytes instead of
         holding them until the next invocation's budget sweep.  The thread
-        holds only a weakref to the scheduler: a dropped node (benchmarks
-        build short-lived per-policy fleets) is GC-able without an explicit
-        ``stop_reaper`` and its reaper exits on the next tick."""
+        holds only a weakref to the scheduler: a dropped node is GC-able
+        without an explicit ``stop_reaper`` and its reaper exits on the
+        next tick."""
         import weakref
 
         self.stop_reaper()
@@ -762,7 +734,7 @@ class NodeScheduler:
 
     def drain_residual(self, timeout: float = 60.0) -> bool:
         """Block until every residual stream has drained and every WARMING
-        instance finalized (benchmarks/eviction barriers)."""
+        instance finalized (eviction barriers)."""
         deadline = time.time() + timeout
         while time.time() < deadline:
             with self._slock:
@@ -823,7 +795,7 @@ class NodeScheduler:
         )
 
     # ------------------------------------------------- residual finalization
-    def _watch_residual(self, fname, inst, state, getter, stats) -> None:
+    def _watch_residual(self, fname, inst, state, stats) -> None:
         """Track a WARMING instance's residual stream and finalize WARM (on
         a dedicated thread) once it drains; a failed residual evicts."""
         with self._slock:
@@ -836,7 +808,7 @@ class NodeScheduler:
                     # stalled residual: never leave an unevictable WARMING
                     # instance pinned against the budget forever
                     raise TimeoutError(f"{fname}: residual stream stalled")
-                resolved = getter(state)
+                resolved = wait_tree(state)
                 with inst.cond:
                     if (
                         inst.state is InstanceState.WARMING
@@ -920,7 +892,6 @@ class NodeScheduler:
 
         fname = inv.function
         prompt, max_new_tokens = inv.prompt, inv.max_new_tokens
-        mode = inv.mode
         if inv.payload is not None:
             # colocated compute lane: no spec, no snapshot, no instance —
             # the thunk runs on this worker after waiting its turn in the
@@ -997,7 +968,7 @@ class NodeScheduler:
                         inst.cond.wait(timeout=0.05)
                 else:  # COLD / EVICTED — this thread owns the restore
                     role = "owner"
-                    inst.begin_restore(mode)
+                    inst.begin_restore()
                     # EVICTED → RESTORING with a pinned working set: hand
                     # the resident ws to the restorer so only the dropped
                     # residual bytes are read again
@@ -1046,7 +1017,7 @@ class NodeScheduler:
                 dt = time.perf_counter() - t0
                 self._bump("joined_restores")
                 return InvokeResult(
-                    toks, cold=True, mode=mode, ttft_s=ttft, total_s=dt,
+                    toks, cold=True, mode="spice", ttft_s=ttft, total_s=dt,
                     function=fname, queue_s=queue_s, joined=True, node=self.name,
                 )
 
@@ -1066,27 +1037,20 @@ class NodeScheduler:
                 # pinned_region rides along: the spice restorer resizes it
                 # in place into the new ws region, so the resident pinned
                 # bytes stay charged across the re-restore
-                state, stats, getter, regions, stream = self._cold_restore(
-                    spec, mode, inv.simulate_read_bw, preloaded, pinned_region,
+                state, stats, regions, stream = self._cold_restore(
+                    spec, preloaded, pinned_region,
                     io_priority=inv.qos.io_priority, on_working_set=_ws_ready,
                     span_args=span_args,
                 )
                 with inst.cond:
-                    inst.publish_restore(state, getter, stats, regions)
+                    inst.publish_restore(state, wait_tree, stats, regions)
                     generation = inst.generation
-                if stream is not None:
-                    # arm mid-restore cancellation: aborts the stream (which
-                    # releases every ledger reservation through the restore
-                    # failure paths) iff no joiner shares the handle tree
-                    handle._attach_canceller(
-                        self._restore_canceller(inst, stream, generation)
-                    )
-                else:
-                    # synchronous restore: baseline modes never fire the
-                    # callback; spice_sync already did (don't re-record)
-                    handle._pin()
-                    if handle.event_ts(EVT_WS_READY) is None:
-                        handle.record(EVT_WS_READY)
+                # arm mid-restore cancellation: aborts the stream (which
+                # releases every ledger reservation through the restore
+                # failure paths) iff no joiner shares the handle tree
+                handle._attach_canceller(
+                    self._restore_canceller(inst, stream, generation)
+                )
                 restore_wait = time.perf_counter() - t0  # sync restore part
                 handle.record(EVT_RUNNING)
                 if inv.prewarm:
@@ -1095,15 +1059,13 @@ class NodeScheduler:
                     toks, ttft = _EMPTY_TOKENS, 0.0
                 else:
                     toks, ttft = generate(
-                        cfg, getter, state, prompt, max_new_tokens, span_args
+                        cfg, wait_tree, state, prompt, max_new_tokens, span_args
                     )
                 ttl = self.keepalive.ttl_for(spec)
                 now = time.time()
                 if (
-                    isinstance(stats, RestoreStats)
-                    and stats.residual_tensors > 0
+                    stats.residual_tensors > 0
                     and ttl > 0
-                    and getter is not None
                     # two-phase promotion: WARM-at-working-set.  Wait only
                     # for the traced working set, promote to WARMING so the
                     # next invocations route warm immediately, and finalize
@@ -1117,16 +1079,15 @@ class NodeScheduler:
                         inst.promote_warming(ttl, now, est_bytes=stats.image_bytes)
                         inst.counters["ws_promotions"] += 1
                     self._bump("ws_promotions")
-                    self._watch_residual(fname, inst, state, getter, stats)
+                    self._watch_residual(fname, inst, state, stats)
                     total = time.perf_counter() - t0
                 else:
-                    if isinstance(stats, RestoreStats):
-                        # snapshot-consistent stats: wait for the stream to
-                        # finish (it closes the JIF reader) before reporting
-                        stats.wait_complete(timeout=300)
+                    # snapshot-consistent stats: wait for the stream to
+                    # finish (it closes the JIF reader) before reporting
+                    stats.wait_complete(timeout=300)
                     total = time.perf_counter() - t0
                     with inst.cond:
-                        resolved = getter(state) if (getter and ttl > 0) else state
+                        resolved = wait_tree(state) if ttl > 0 else state
                         inst.promote_warm(resolved, ttl, now)
             except BaseException:
                 with inst.cond:
@@ -1137,14 +1098,13 @@ class NodeScheduler:
             # needs it, so it must not inflate the cold-start count
             self._bump("speculative_restores" if inv.prewarm else "cold_starts")
             if ttl > 0:
-                self._charge_warm_instance(inst)
                 self._enforce_budget(keep=fname)
             return InvokeResult(
-                toks, cold=True, mode="prewarm" if inv.prewarm else mode,
+                toks, cold=True, mode="prewarm" if inv.prewarm else "spice",
                 restore_wait_s=restore_wait,
                 ttft_s=restore_wait + ttft,  # time-to-first-token from request
                 total_s=total,
-                stats=stats.as_dict() if stats else None,
+                stats=stats.as_dict(),
                 function=fname, queue_s=queue_s, node=self.name,
             )
         finally:
@@ -1194,11 +1154,6 @@ class NodeScheduler:
                 freed += got
                 self._bump("residual_evictions")
         return freed
-
-    def _reclaim_pool(self, nbytes: int, protect=frozenset()) -> int:
-        """Ladder rung 2: trim the pool's free staging buffers (the pool
-        may have been swapped since registration, so resolve it live)."""
-        return self._pool.reclaim(nbytes, protect)
 
     def _reclaim_warm_lru(self, nbytes: int, protect=frozenset()) -> int:
         """Ladder rung 3: first drop pinned working sets of residual-evicted
@@ -1281,129 +1236,28 @@ class NodeScheduler:
             return (lambda a: jnp.array(a, copy=True)), None
         raise ValueError(f"unknown install policy {self.install!r}")
 
-    @staticmethod
-    def _baseline_install(transform, device_path):
-        """Per-leaf install for baseline modes (no upload ring there):
-        fused degrades to an eager device copy, host stays a no-op."""
-        if transform is not None:
-            return transform
-        if device_path is not None:
-            return device_path.installer()
-        return lambda a: a
-
-    def _cold_restore(self, spec: FunctionSpec, mode: str, sim_bw=None,
-                      preloaded=None, pinned_region=None, io_priority: int = 0,
+    def _cold_restore(self, spec: FunctionSpec, preloaded=None,
+                      pinned_region=None, io_priority: int = 0,
                       on_working_set=None, span_args=None):
-        """Returns (state, stats, getter, (ws_region, residual_region),
-        stream).  Spice restores reserve their regions up front through the
-        node ledger — a restore that cannot fit fails fast
-        (MemoryPressureError) or triggers the reclaim ladder instead of
-        over-committing.  ``pinned_region`` (a residual-evicted instance's
-        retained ws charge) transfers into the spice restore's ws region;
-        baseline modes re-read everything, so it is released here.
-        ``io_priority`` (the QoS class's stream priority) ranks this
-        restore's reads at the shared arbiter; ``stream`` is the live
-        prefetch stream for cancellation (None for baseline modes).
-        ``span_args`` label a spice restore's spans with the owning
+        """Returns (state, stats, (ws_region, residual_region), stream).
+        The restore reserves its regions up front through the node ledger —
+        a restore that cannot fit fails fast (MemoryPressureError) or
+        triggers the reclaim ladder instead of over-committing.
+        ``pinned_region`` (a residual-evicted instance's retained ws charge)
+        transfers into the restore's ws region.  ``io_priority`` (the QoS
+        class's stream priority) ranks this restore's reads at the shared
+        arbiter; ``stream`` is the live prefetch stream for cancellation.
+        ``span_args`` label the restore's spans with the owning
         invocation's ``function`` and ``req``."""
-        if pinned_region is not None and mode not in ("spice", "spice_sync"):
-            pinned_region.release()
-            pinned_region = None
         transform, device_path = self._install_policy()
-        install = self._baseline_install(transform, device_path)
-        if mode == "spice":
-            restorer = SpiceRestorer(
-                pool=self.pool, node_cache=self.node_cache,
-                transform=transform, simulate_read_bw=sim_bw,
-                iosched=self.iosched, memory=self.memory,
-                stream_priority=io_priority, device_path=device_path,
-                chunks=self.chunks, span_args=span_args,
-            )
-            state, meta, handles, stats = restorer.restore(
-                spec.jif_path, wait=False, preloaded=preloaded,
-                preloaded_region=pinned_region, on_working_set=on_working_set,
-            )
-            return state, stats, wait_tree, restorer.regions, restorer.stream
-        if mode == "spice_sync":
-            restorer = SpiceRestorer(
-                pool=self.pool, node_cache=self.node_cache, pipelined=False,
-                transform=transform, simulate_read_bw=sim_bw,
-                iosched=self.iosched, memory=self.memory,
-                stream_priority=io_priority, device_path=device_path,
-                chunks=self.chunks, span_args=span_args,
-            )
-            state, meta, handles, stats = restorer.restore(
-                spec.jif_path, wait=True, preloaded=preloaded,
-                preloaded_region=pinned_region, on_working_set=on_working_set,
-            )
-            # inline stream already drained: nothing left to cancel
-            return state, stats, None, restorer.regions, None
-        if mode == "criu_star":
-            state, stats = baselines.criu_star_restore(
-                spec.jif_path.replace(".jif", ".criu"), simulate_read_bw=sim_bw
-            )
-            state = jax.tree.map(install, state)
-            return state, stats, None, (self._charge_baseline(spec, state), None), None
-        if mode == "reap_star":
-            state, stats = baselines.reap_star_restore(
-                spec.jif_path.replace(".jif", ".mono"), simulate_read_bw=sim_bw
-            )
-            state = jax.tree.map(install, state)
-            return state, stats, None, (self._charge_baseline(spec, state), None), None
-        if mode == "faasnap_star":
-            r = baselines.FaasnapAsyncRestorer(
-                spec.jif_path.replace(".jif", ".mono"), simulate_read_bw=sim_bw
-            )
-            # rebuild a handle-like tree backed by ensure()
-            leaves = {
-                t["name"]: _FaasnapLeaf(r, t["name"])
-                for t in r.r.header["tensors"]
-                if not t["name"].startswith("__extra__/")
-            }
-            state = unflatten_state(r.r.header["tree"], leaves)
-            return state, r.stats, faasnap_wait, (None, None), None
-        raise ValueError(f"unknown restore mode {mode!r}")
-
-    def _charge_baseline(self, spec: FunctionSpec, state):
-        """Baseline restores bypass the spice admission path; charge their
-        resident bytes to the ledger anyway so eviction pressure sees them.
-        Best-effort: a baseline run on an over-subscribed node proceeds
-        uncharged (the measured systems never refused admission either)."""
-        try:
-            return self.memory.reserve(
-                _tree_nbytes(state), KIND_WORKING_SET,
-                owner=spec.name, timeout=5.0, protect=(spec.name,),
-            )
-        except MemoryPressureError:
-            return None
-
-    def _charge_warm_instance(self, inst: FunctionInstance) -> None:
-        """Post-promotion charge for instances that reached WARM without
-        ledger regions — baseline modes whose state only materialized at
-        promotion (faasnap's lazy fault-in tree).  Without this, their warm
-        residency would be invisible to budget pressure."""
-        with inst.cond:
-            if inst.state is not InstanceState.WARM or inst.ws_region is not None:
-                return
-            nbytes = inst.memory_bytes
-            generation = inst.generation
-            fname = inst.spec.name
-        if not nbytes:
-            return
-        try:
-            region = self.memory.reserve(
-                nbytes, KIND_WORKING_SET, owner=fname,
-                timeout=5.0, protect=(fname,),
-            )
-        except MemoryPressureError:
-            return  # best-effort, like _charge_baseline
-        region.commit(pinned="working_set")
-        with inst.cond:
-            if (
-                inst.state is InstanceState.WARM
-                and inst.ws_region is None
-                and inst.generation == generation
-            ):
-                inst.ws_region = region
-            else:  # evicted/re-restored while we reserved
-                region.release()
+        restorer = SpiceRestorer(
+            pool=self.pool, node_cache=self.node_cache, transform=transform,
+            iosched=self.iosched, memory=self.memory,
+            stream_priority=io_priority, device_path=device_path,
+            chunks=self.chunks, span_args=span_args,
+        )
+        state, _, _, stats = restorer.restore(
+            spec.jif_path, wait=False, preloaded=preloaded,
+            preloaded_region=pinned_region, on_working_set=on_working_set,
+        )
+        return state, stats, restorer.regions, restorer.stream

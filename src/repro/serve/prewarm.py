@@ -315,8 +315,6 @@ class PrewarmEngine:
         max_inflight: int = 4,
         min_observations: int = 3,
         speculative: bool = True,
-        mode: str = "spice",
-        simulate_read_bw: Optional[float] = None,
     ):
         """``horizon_s``: speculate when the predicted next arrival is
         within this window (pair with a ``PrewarmPolicy.tail_ttl_s``
@@ -331,8 +329,6 @@ class PrewarmEngine:
         self.max_inflight = max_inflight
         self.min_observations = min_observations
         self.speculative = speculative
-        self.mode = mode
-        self.simulate_read_bw = simulate_read_bw
         self._router_ref = None
         self._lock = threading.Lock()
         self._inflight: Dict[str, InvocationHandle] = {}
@@ -462,8 +458,6 @@ class PrewarmEngine:
                 function=fname,
                 prompt=None,
                 max_new_tokens=0,
-                mode=self.mode,
-                simulate_read_bw=self.simulate_read_bw,
                 qos=QosClass.BATCH,
                 prewarm=True,
             )

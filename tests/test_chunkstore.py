@@ -268,12 +268,6 @@ def test_chunk_cache_ram_reject_keeps_disk_tier(tmp_path):
 
 
 # ------------------------------------------------------ dedup-aware restore
-def _dedup_restorer(tmp_path, cache):
-    return SpiceRestorer(
-        node_cache=NodeImageCache(), chunks=cache, pipelined=False
-    )
-
-
 def test_dedup_restore_is_byte_identical_and_skips_shared_reads(tmp_path):
     base = rng_state(5, tail=True)
     parent = str(tmp_path / "p.jif")
@@ -296,9 +290,9 @@ def test_dedup_restore_is_byte_identical_and_skips_shared_reads(tmp_path):
     store = ChunkStore(str(tmp_path / "cas"))
     cache = NodeChunkCache(store, node="n0")
     shared_images = NodeImageCache()
-    r1 = SpiceRestorer(node_cache=shared_images, chunks=cache, pipelined=False)
+    r1 = SpiceRestorer(node_cache=shared_images, chunks=cache)
     got_a, _, _, st_a = r1.restore(pa)
-    r2 = SpiceRestorer(node_cache=shared_images, chunks=cache, pipelined=False)
+    r2 = SpiceRestorer(node_cache=shared_images, chunks=cache)
     got_b, _, _, st_b = r2.restore(pb)
 
     # dedup must never change restored bytes
@@ -321,8 +315,8 @@ def test_dedup_restore_of_v1_image_via_backfill(tmp_path):
     plain, _, _, _ = SpiceRestorer().restore(p1)
     store = ChunkStore(str(tmp_path / "cas"))
     cache = NodeChunkCache(store, node="n0")
-    _, _, _, st1 = SpiceRestorer(chunks=cache, pipelined=False).restore(p1)
-    got, _, _, st2 = SpiceRestorer(chunks=cache, pipelined=False).restore(p2)
+    _, _, _, st1 = SpiceRestorer(chunks=cache).restore(p1)
+    got, _, _, st2 = SpiceRestorer(chunks=cache).restore(p2)
     assert_state_equal(plain, got)
     assert st1.bytes_read > 0
     assert st2.bytes_read == 0  # content-identical copy: all cache hits
@@ -341,7 +335,7 @@ def test_router_wires_peer_fetch_between_node_caches(tmp_path):
                       chunks=NodeChunkCache(store, node=f"node{i}"))
         for i in range(2)
     ]
-    router = ClusterRouter(catalog, nodes, interconnect_bw=1e9)
+    router = ClusterRouter(catalog, nodes)
     data = os.urandom(PAGE)
     dk = chunk_digest(data)
     nodes[0].chunks.ingest(dk, data)  # announces into the catalog index

@@ -1,6 +1,6 @@
 """Cluster serving layer: control-plane/data-plane split (FunctionCatalog
 vs NodeScheduler), snapshot-locality-aware placement across N nodes, sticky
-join routing, the scale-out knob, and registry persistence under the split."""
+join routing, and registry persistence under the split."""
 import threading
 import time
 
@@ -41,7 +41,7 @@ def catalog_with_zoo(tmp_path_factory):
                         formats=("jif",))
     # compile-cache warmup through a throwaway single node
     node = NodeScheduler(registry=catalog.registry)
-    node.invoke("cl-a", PROMPT, max_new_tokens=2, mode="spice_sync", cfg=cfg)
+    node.invoke("cl-a", PROMPT, max_new_tokens=2, cfg=cfg)
     return catalog, cfg, str(d)
 
 
@@ -67,7 +67,7 @@ def test_registry_roundtrip_under_catalog_split(catalog_with_zoo, tmp_path):
     serves invocations on a brand-new node with identical tokens."""
     catalog, cfg, _ = catalog_with_zoo
     ref = _cluster(catalog, n=1).invoke(
-        "cl-b", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg
+        "cl-b", PROMPT, max_new_tokens=3, cfg=cfg
     )
 
     path = str(tmp_path / "registry.json")
@@ -80,7 +80,7 @@ def test_registry_roundtrip_under_catalog_split(catalog_with_zoo, tmp_path):
     )
 
     node = ServerlessNode(catalog=loaded)
-    r = node.invoke("cl-b", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+    r = node.invoke("cl-b", PROMPT, max_new_tokens=3, cfg=cfg)
     assert r.cold and r.node == ""  # single-node path: empty node name
     np.testing.assert_array_equal(r.tokens, ref.tokens)
 
@@ -94,7 +94,7 @@ def test_single_node_facade_keeps_surface(catalog_with_zoo, tmp_path):
     node.publish("fac-fn", cfg, params, str(tmp_path), warm_ttl_s=60,
                  formats=("jif",))
     assert node.catalog.stats["publishes"] == 1
-    r = node.invoke("fac-fn", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    r = node.invoke("fac-fn", PROMPT, max_new_tokens=2, cfg=cfg)
     assert r.cold
     order = node.record_access("fac-fn", PROMPT, max_new_tokens=2, cfg=cfg)
     assert order and node.catalog.recorded_order("fac-fn") == order
@@ -107,8 +107,8 @@ def test_single_node_facade_keeps_surface(catalog_with_zoo, tmp_path):
 def test_locality_first_sticks_and_second_invoke_is_warm(catalog_with_zoo):
     catalog, cfg, _ = catalog_with_zoo
     router = _cluster(catalog)
-    r1 = router.invoke("cl-a", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
-    r2 = router.invoke("cl-a", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    r1 = router.invoke("cl-a", PROMPT, max_new_tokens=2, cfg=cfg)
+    r2 = router.invoke("cl-a", PROMPT, max_new_tokens=2, cfg=cfg)
     assert r1.cold and not r2.cold
     assert r1.node == r2.node and r1.node.startswith("node")
     assert router.replicas("cl-a") == [r1.node]
@@ -116,15 +116,16 @@ def test_locality_first_sticks_and_second_invoke_is_warm(catalog_with_zoo):
     router.audit()
 
 
-def test_concurrent_burst_joins_on_one_node_zero_duplicate_colds(catalog_with_zoo):
+def test_concurrent_burst_joins_on_one_node_zero_duplicate_colds(
+        catalog_with_zoo, slow_reads):
     """Single population per cluster: a burst of one function's invocations
     rides ONE restore on ONE node — no duplicate concurrent cold restores
     anywhere in the fleet."""
     catalog, cfg, _ = catalog_with_zoo
+    slow_reads(5e8, catalog.registry.get("cl-b").jif_path)
     router = _cluster(catalog)
     futs = [
-        router.submit("cl-b", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg,
-                      simulate_read_bw=5e8)
+        router.submit("cl-b", PROMPT, max_new_tokens=2, cfg=cfg)
         for _ in range(5)
     ]
     results = [f.result() for f in futs]
@@ -145,23 +146,23 @@ def test_round_robin_spreads_while_locality_does_not(catalog_with_zoo):
     router = _cluster(catalog, placement=RoundRobin())
     nodes_hit = []
     for _ in range(3):
-        r = router.invoke("cl-c", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+        r = router.invoke("cl-c", PROMPT, max_new_tokens=2, cfg=cfg)
         nodes_hit.append(r.node)
         assert r.cold  # every placement is a fresh node: always cold
     assert len(set(nodes_hit)) == 3
     router.audit()
 
 
-def test_least_loaded_avoids_busy_node(catalog_with_zoo):
+def test_least_loaded_avoids_busy_node(catalog_with_zoo, slow_reads):
     catalog, cfg, _ = catalog_with_zoo
     router = _cluster(catalog, n=2, placement=LeastLoaded())
     # jam node0 with a slow restore, then place a different function
-    f0 = router.nodes[0].submit("cl-a", PROMPT, max_new_tokens=2, mode="spice",
-                                cfg=cfg, simulate_read_bw=2e7)
+    slow_reads(2e7, catalog.registry.get("cl-a").jif_path)
+    f0 = router.nodes[0].submit("cl-a", PROMPT, max_new_tokens=2, cfg=cfg)
     deadline = time.time() + 5
     while router.nodes[0].load().queue_depth == 0 and time.time() < deadline:
         time.sleep(0.005)
-    r = router.invoke("cl-b", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    r = router.invoke("cl-b", PROMPT, max_new_tokens=2, cfg=cfg)
     assert r.node == "node1"
     f0.result()
     router.audit()
@@ -188,7 +189,7 @@ def test_locality_first_prefers_cached_base_image(catalog_with_zoo, tmp_path):
 
     router = _cluster(catalog)
     router.nodes[2].node_cache.put(img, evictable=False)  # only node2 has it
-    r = router.invoke("tier-fn", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    r = router.invoke("tier-fn", PROMPT, max_new_tokens=2, cfg=cfg)
     assert r.node == "node2"
     assert router.nodes[2].node_cache.stats["base_bytes_served"] > 0
     router.audit()
@@ -211,14 +212,14 @@ def test_locality_first_prefers_delta_parent_cached_node(catalog_with_zoo, tmp_p
                         warm_ttl_s=3600.0, formats=("jif",))
 
     router = _cluster(catalog)
-    r1 = router.invoke("delta-x", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    r1 = router.invoke("delta-x", PROMPT, max_new_tokens=2, cfg=cfg)
     serving = router.node(r1.node)
     key = catalog.locality_key("delta-x")
     assert key is not None and serving.node_cache.contains(key)
     assert catalog.locality_key("delta-y") == key  # same parent chain
 
     # sibling delta: the parent-cached node must win placement
-    r2 = router.invoke("delta-y", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    r2 = router.invoke("delta-y", PROMPT, max_new_tokens=2, cfg=cfg)
     assert r2.node == r1.node
     # ...and the parent was bootstrapped exactly once cluster-wide
     assert sum(1 for n in router.nodes if n.node_cache.contains(key)) == 1
@@ -231,37 +232,21 @@ def test_locality_first_prefers_delta_parent_cached_node(catalog_with_zoo, tmp_p
 
     spec = catalog.registry.get("delta-x")
     size_before = os.path.getsize(spec.jif_path)
-    order = router.record_access("delta-x", prompt=PROMPT, max_new_tokens=2,
-                                 cfg=cfg)
+    order = router.record_access("delta-x", prompt=PROMPT, max_new_tokens=2, cfg=cfg)
     stats = router.relayout("delta-x")
     assert stats.parent == os.path.abspath(parent_path)
     assert os.path.getsize(spec.jif_path) < 0.6 * os.path.getsize(parent_path) \
         or os.path.getsize(spec.jif_path) <= 1.2 * size_before
     assert catalog.locality_key("delta-x") is not None
     r3 = ClusterRouter(catalog, [NodeScheduler(registry=catalog.registry)]) \
-        .invoke("delta-x", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+        .invoke("delta-x", PROMPT, max_new_tokens=2, cfg=cfg)
     np.testing.assert_array_equal(r3.tokens, r1.tokens)
-
-
-def test_scale_out_knob_spawns_second_replica(catalog_with_zoo):
-    catalog, cfg, _ = catalog_with_zoo
-    router = _cluster(catalog, scale_out_queue_depth=1)
-    futs = [
-        router.submit("cl-a", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg,
-                      simulate_read_bw=5e7)
-        for _ in range(6)
-    ]
-    for f in futs:
-        f.result()
-    assert len(router.replicas("cl-a")) >= 2
-    assert router.stats["scale_outs"] >= 1
-    router.audit()
 
 
 def test_node_load_probe_surface(catalog_with_zoo):
     catalog, cfg, _ = catalog_with_zoo
     router = _cluster(catalog, n=2)
-    r = router.invoke("cl-a", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    r = router.invoke("cl-a", PROMPT, max_new_tokens=2, cfg=cfg)
     router.drain_residual()
     loads = {l.node: l for l in router.loads()}
     assert set(loads) == {"node0", "node1"}
@@ -294,8 +279,8 @@ def test_custom_keepalive_victims_ordering(catalog_with_zoo, tmp_path):
     # policy must pick cl-b regardless
     node = NodeScheduler(registry=catalog.registry,
                          keepalive=EvictNamedFirst("cl-b"))
-    node.invoke("cl-b", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
-    node.invoke("cl-a", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    node.invoke("cl-b", PROMPT, max_new_tokens=2, cfg=cfg)
+    node.invoke("cl-a", PROMPT, max_new_tokens=2, cfg=cfg)
     node.drain_residual()
     inst_b = node.instance("cl-b")
     inst_b.last_used = time.time() + 100  # force MRU: LRU would never pick it

@@ -1,5 +1,5 @@
 """Core snapshot/restore tests: JIF round-trips, overlay dedup invariants,
-pipelined restore correctness, baselines, pool/cache behaviour."""
+restore correctness, baselines, pool/cache behaviour."""
 import os
 import threading
 
@@ -250,13 +250,14 @@ def test_pool_concurrent_acquire_release():
     assert pool.held_bytes <= pool.capacity
 
 
-def test_restore_stats_snapshot_consistent(tmp_path):
+def test_restore_stats_snapshot_consistent(tmp_path, slow_reads):
     """wait=False stats must expose completion; totals are only final (and
     the JifReader only closed) once the stream has drained."""
     state = rng_state(1, scale=8)
     path = str(tmp_path / "f.jif")
     snapshot(state, path, page_size=PAGE)
-    restorer = SpiceRestorer(simulate_read_bw=5e8)
+    slow_reads(5e8)
+    restorer = SpiceRestorer()
     _, _, handles, stats = restorer.restore(path, wait=False)
     d = stats.as_dict()
     assert "complete" in d  # snapshot carries its own consistency marker

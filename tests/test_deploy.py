@@ -47,7 +47,7 @@ def deployed(tmp_path_factory):
                         formats=("jif",))
         zoo[fname] = params
     node = NodeScheduler(registry=catalog.registry)
-    node.invoke("dp-a", PROMPT, max_new_tokens=2, mode="spice_sync", cfg=cfg)
+    node.invoke("dp-a", PROMPT, max_new_tokens=2, cfg=cfg)
     return catalog, cfg, str(d), zoo, store
 
 
@@ -193,7 +193,7 @@ def test_canary_gate_promotes_over_router(deployed, tmp_path):
 
     # the router resolves the logical name through the controller
     results = [
-        router.invoke("dp-e", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+        router.invoke("dp-e", PROMPT, max_new_tokens=2, cfg=cfg)
         for _ in range(8)
     ]
     served = {r.function for r in results}
@@ -232,7 +232,7 @@ def test_colocated_batch_training_never_starves_latency(deployed):
         admission=AdmissionController(max_batch_inflight=1),
     )
     # warm the serving function first
-    r = node.invoke("dp-c", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    r = node.invoke("dp-c", PROMPT, max_new_tokens=2, cfg=cfg)
     assert r.cold
 
     # a second concurrent BATCH payload is REFUSED: the in-flight cap keeps
@@ -261,7 +261,7 @@ def test_colocated_batch_training_never_starves_latency(deployed):
         for _ in range(5):
             lr = node.submit_invocation(Invocation(
                 function="dp-c", prompt=PROMPT, max_new_tokens=2,
-                mode="spice", cfg=cfg, qos=QosClass.LATENCY,
+                cfg=cfg, qos=QosClass.LATENCY,
             )).result(10.0)
             assert not lr.cold          # stayed warm throughout
             assert lr.queue_wait_s < 0.25  # never parked behind training

@@ -1,14 +1,21 @@
-"""Serving engine: publish -> cold start under every restore mode -> warm;
-all modes must produce identical tokens; spice overlap must be observable."""
+"""Serving engine: publish -> cold start -> warm; the paper's baseline
+restorers, run standalone, must rebuild the same tree and tokens as a
+spice restore."""
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
-from repro.core import BaseImage
+from repro.core import BaseImage, SpiceRestorer, baselines
+from repro.core.treeutil import flatten_state
 from repro.models import lm
-from repro.serve.engine import ServerlessNode, layer_sequence, layerwise_state
+from repro.serve.engine import (
+    ServerlessNode,
+    generate,
+    layer_sequence,
+    layerwise_state,
+)
 
 ARCH = "qwen1.5-0.5b"
 
@@ -20,6 +27,7 @@ def node_with_fn(tmp_path_factory):
     params = lm.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
     node = ServerlessNode()
     node.publish("f1", cfg, params, str(d), warm_ttl_s=60.0,
+                 formats=("jif", "criu", "monolith"),
                  extra_state={"opt_m": np.ones((1 << 16,), np.float32)})
     return node, cfg
 
@@ -27,24 +35,41 @@ def node_with_fn(tmp_path_factory):
 PROMPT = np.array([[5, 6, 7, 8, 9, 10]], dtype=np.int32)
 
 
-def test_all_modes_agree(node_with_fn):
+BASELINES = {  # restore of a function's image in the baseline's format
+    "criu_star": lambda jif: baselines.criu_star_restore(
+        jif.replace(".jif", ".criu"))[0],
+    "reap_star": lambda jif: baselines.reap_star_restore(
+        jif.replace(".jif", ".mono"))[0],
+    "faasnap_star": lambda jif: baselines.FaasnapAsyncRestorer(
+        jif.replace(".jif", ".mono")).state(),
+}
+
+
+@pytest.mark.parametrize("baseline", sorted(BASELINES))
+def test_all_modes_agree(node_with_fn, baseline):
     node, cfg = node_with_fn
-    outs = {}
-    for mode in ["spice", "spice_sync", "criu_star", "reap_star", "faasnap_star"]:
-        node.evict()
-        r = node.invoke("f1", PROMPT, max_new_tokens=6, mode=mode, cfg=cfg)
-        assert r.cold
-        outs[mode] = r.tokens
-    base = outs["spice"]
-    for mode, toks in outs.items():
-        np.testing.assert_array_equal(toks, base, err_msg=mode)
-    assert base.shape == (1, 6)
+    jif = node.registry.get("f1").jif_path
+    spice, _, _, _ = SpiceRestorer(node_cache=node.node_cache).restore(jif)
+    want = {n: a for n, a in flatten_state(spice)[0]
+            if not n.startswith("__extra__/")}
+    got = BASELINES[baseline](jif)
+    leaves = flatten_state(got)[0]
+    assert sorted(n for n, _ in leaves) == sorted(want)
+    for name, arr in leaves:
+        np.testing.assert_array_equal(np.asarray(arr), want[name],
+                                      err_msg=f"{baseline}: {name}")
+    node.evict()
+    r = node.invoke("f1", PROMPT, max_new_tokens=6, cfg=cfg)
+    assert r.cold
+    toks, _ = generate(cfg, None, got, PROMPT, 6)
+    np.testing.assert_array_equal(toks, r.tokens, err_msg=baseline)
+    assert r.tokens.shape == (1, 6)
 
 
 def test_warm_path_matches_cold(node_with_fn):
     node, cfg = node_with_fn
     node.evict()
-    cold = node.invoke("f1", PROMPT, max_new_tokens=4, mode="spice", cfg=cfg)
+    cold = node.invoke("f1", PROMPT, max_new_tokens=4, cfg=cfg)
     warm = node.invoke("f1", PROMPT, max_new_tokens=4, cfg=cfg)
     assert cold.cold and not warm.cold
     np.testing.assert_array_equal(cold.tokens, warm.tokens)
@@ -55,7 +80,7 @@ def test_generation_matches_lm_forward(node_with_fn):
     """Engine layerwise generation == monolithic lm.prefill/decode path."""
     node, cfg = node_with_fn
     node.evict()
-    r = node.invoke("f1", PROMPT, max_new_tokens=3, mode="spice_sync", cfg=cfg)
+    r = node.invoke("f1", PROMPT, max_new_tokens=3, cfg=cfg)
 
     params = lm.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
     logits, caches, _ = lm.prefill(

@@ -33,7 +33,7 @@ def catalog_with_zoo(tmp_path_factory):
                         formats=("jif",))
     # compile-cache warmup through a throwaway node
     node = NodeScheduler(registry=catalog.registry)
-    node.invoke("hf-a", PROMPT, max_new_tokens=2, mode="spice_sync", cfg=cfg)
+    node.invoke("hf-a", PROMPT, max_new_tokens=2, cfg=cfg)
     return catalog, cfg, str(d)
 
 
@@ -58,7 +58,7 @@ def _other(router, name):
 def test_handoff_byte_identical_and_reroutes(catalog_with_zoo, tmp_path):
     catalog, cfg, _ = catalog_with_zoo
     router = _router(catalog)
-    ref = router.invoke("hf-a", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+    ref = router.invoke("hf-a", PROMPT, max_new_tokens=3, cfg=cfg)
     assert ref.cold
     src_name, dst_name = ref.node, _other(router, ref.node)
     src, dst = router.node(src_name), router.node(dst_name)
@@ -83,7 +83,7 @@ def test_handoff_byte_identical_and_reroutes(catalog_with_zoo, tmp_path):
     assert router.replicas("hf-a") == [dst_name]
 
     # the next request is warm ON THE SUCCESSOR, with identical tokens
-    r = router.invoke("hf-a", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+    r = router.invoke("hf-a", PROMPT, max_new_tokens=3, cfg=cfg)
     assert not r.cold and r.node == dst_name
     np.testing.assert_array_equal(r.tokens, ref.tokens)
     router.audit()
@@ -95,7 +95,7 @@ def test_handoff_delta_is_dirty_state_only(catalog_with_zoo, tmp_path):
     image's private payload is a sliver of the full state."""
     catalog, cfg, _ = catalog_with_zoo
     router = _router(catalog)
-    r = router.invoke("hf-b", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    r = router.invoke("hf-b", PROMPT, max_new_tokens=2, cfg=cfg)
     hs = handoff_warm(router, "hf-b", r.node, _other(router, r.node),
                       handoff_dir=str(tmp_path), cfg=cfg)
     assert hs.ok, hs.reason
@@ -105,18 +105,20 @@ def test_handoff_delta_is_dirty_state_only(catalog_with_zoo, tmp_path):
     router.close()
 
 
-def test_inflight_invocation_completes_before_handoff(catalog_with_zoo, tmp_path):
+def test_inflight_invocation_completes_before_handoff(catalog_with_zoo, tmp_path,
+                                                      slow_reads):
     """A handoff issued while the source instance is busy (here: mid
-    restore + generation, throttled by simulate_read_bw) must wait the
-    work out — the caller gets a full result, then the handoff lands."""
+    restore + generation, its image reads paced) must wait the work out —
+    the caller gets a full result, then the handoff lands."""
     catalog, cfg, _ = catalog_with_zoo
     router = _router(catalog)
-    seed = router.invoke("hf-c", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+    seed = router.invoke("hf-c", PROMPT, max_new_tokens=3, cfg=cfg)
     src_name, dst_name = seed.node, _other(router, seed.node)
     src = router.node(src_name)
     src.evict("hf-c")
-    fut = src.submit("hf-c", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg,
-                     simulate_read_bw=5e7)  # slow restore: instance is busy
+    # slow restore: instance is busy
+    slow_reads(5e7, catalog.registry.get("hf-c").jif_path)
+    fut = src.submit("hf-c", PROMPT, max_new_tokens=3, cfg=cfg)
     deadline = time.time() + 10
     while time.time() < deadline:  # wait until the restore is in flight
         inst = src.instance("hf-c")
@@ -151,7 +153,7 @@ def test_drain_returns_ledger_to_prerestore_residency(catalog_with_zoo, tmp_path
     catalog, cfg, _ = catalog_with_zoo
     router = _router(catalog)
     baseline = {n.name: n.memory.held_bytes() for n in router.nodes}
-    r = router.invoke("hf-a", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    r = router.invoke("hf-a", PROMPT, max_new_tokens=2, cfg=cfg)
     src = router.node(r.node)
     assert src.memory.held_bytes() > baseline[r.node]  # warm state resident
 
@@ -169,7 +171,7 @@ def test_drain_returns_ledger_to_prerestore_residency(catalog_with_zoo, tmp_path
     assert src.memory.held_bytes() == baseline[r.node]
     src.memory.audit()
     # ...and the warm state survived on the successor: next request warm
-    r2 = router.invoke("hf-a", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    r2 = router.invoke("hf-a", PROMPT, max_new_tokens=2, cfg=cfg)
     assert not r2.cold and r2.node != r.node
     np.testing.assert_array_equal(r2.tokens, r.tokens)
     router.audit()
@@ -181,13 +183,13 @@ def test_drain_without_handoff_forces_future_cold_start(catalog_with_zoo, tmp_pa
     next request pays a cold restore — exactly what handoff eliminates."""
     catalog, cfg, _ = catalog_with_zoo
     router = _router(catalog)
-    r = router.invoke("hf-b", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    r = router.invoke("hf-b", PROMPT, max_new_tokens=2, cfg=cfg)
     scaler = AutoScaler(router, [], handoff_dir=str(tmp_path), min_nodes=1,
                         handoff=False)
     scaler.drain_node(r.node)
     assert scaler.stats["drain_evictions"] == 1
     assert scaler.stats["handoffs_ok"] == 0
-    r2 = router.invoke("hf-b", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    r2 = router.invoke("hf-b", PROMPT, max_new_tokens=2, cfg=cfg)
     assert r2.cold
     router.audit()
     router.close()
@@ -249,7 +251,7 @@ def test_autoscaler_scales_out_on_sustained_violation(catalog_with_zoo, tmp_path
     assert scaler.tick() is None  # max_nodes caps further growth
     # the grown node serves traffic (registry adopted, monitor wired)
     grown = router.nodes[-1]
-    r = grown.invoke("hf-c", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    r = grown.invoke("hf-c", PROMPT, max_new_tokens=2, cfg=cfg)
     assert r.cold and grown.on_result == mon.observe
     router.audit()
     router.close()
@@ -258,7 +260,7 @@ def test_autoscaler_scales_out_on_sustained_violation(catalog_with_zoo, tmp_path
 def test_autoscaler_scales_in_on_sustained_slack(catalog_with_zoo, tmp_path):
     catalog, cfg, _ = catalog_with_zoo
     router = _router(catalog, n=3)
-    r = router.invoke("hf-a", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    r = router.invoke("hf-a", PROMPT, max_new_tokens=2, cfg=cfg)
     scaler = AutoScaler(
         router, [ServiceSLO(QosClass.LATENCY, ttft_p99_s=0.5)],
         handoff_dir=str(tmp_path), min_nodes=2, scale_in_after=2,
@@ -284,7 +286,7 @@ def test_load_probe_cache_invalidated_by_lifecycle_edge(catalog_with_zoo):
                          keepalive=FixedTTLPolicy(3600.0), load_ttl_s=30.0)
     l1 = node.load()
     assert node.load() is l1  # within TTL, no transitions: cached snapshot
-    node.invoke("hf-a", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    node.invoke("hf-a", PROMPT, max_new_tokens=2, cfg=cfg)
     l2 = node.load()  # lifecycle edges bumped the epoch despite the TTL
     assert l2 is not l1 and "hf-a" in l2.warm
     node.evict("hf-a")

@@ -35,7 +35,7 @@ from repro.serve.node import FixedTTLPolicy, NodeScheduler
 
 ARCH = "qwen1.5-0.5b"
 PROMPT = np.array([[5, 3, 1, 7, 2, 6]], dtype=np.int32)
-SLOW_BW = 2e7  # simulated read bandwidth that keeps a restore in flight
+SLOW_BW = 2e7  # paced read bandwidth that keeps a restore in flight
 
 
 @pytest.fixture(scope="module")
@@ -52,7 +52,7 @@ def qzoo(tmp_path_factory):
                         formats=("jif",), extra_state=extra)
     node = NodeScheduler(registry=catalog.registry)
     ref = {
-        f: node.invoke(f, PROMPT, max_new_tokens=3, mode="spice_sync", cfg=cfg).tokens
+        f: node.invoke(f, PROMPT, max_new_tokens=3, cfg=cfg).tokens
         for f in ["q-a", "q-b"]
     }
     return catalog, cfg, ref
@@ -65,6 +65,10 @@ def _node(catalog, **kwargs):
 
 def _evts(handle):
     return [e for e, _ in handle.events()]
+
+
+def _jif(catalog, fname):
+    return catalog.registry.get(fname).jif_path
 
 
 # ------------------------------------------------------------ typed surface
@@ -108,11 +112,11 @@ def test_legacy_submit_handle_ducktypes_future(qzoo):
 
 
 # ------------------------------------------------------------- cancellation
-def test_cancel_while_queued_never_runs(qzoo):
+def test_cancel_while_queued_never_runs(qzoo, slow_reads):
     catalog, cfg, ref = qzoo
+    slow_reads(SLOW_BW, _jif(catalog, "q-a"))
     node = _node(catalog, max_workers=1)
-    jam = node.submit_invocation(Invocation(
-        "q-a", PROMPT, 2, cfg=cfg, simulate_read_bw=SLOW_BW))
+    jam = node.submit_invocation(Invocation("q-a", PROMPT, 2, cfg=cfg))
     queued = node.submit_invocation(Invocation("q-b", PROMPT, 2, cfg=cfg))
     assert queued.cancel()
     assert queued.cancel()  # idempotent
@@ -127,12 +131,12 @@ def test_cancel_while_queued_never_runs(qzoo):
     node.memory.audit()
 
 
-def test_cancel_mid_restoring_aborts_stream_and_releases_ledger(qzoo):
+def test_cancel_mid_restoring_aborts_stream_and_releases_ledger(qzoo, slow_reads):
     catalog, cfg, ref = qzoo
+    slow_reads(SLOW_BW, _jif(catalog, "q-a"))
     node = _node(catalog)
     h = node.submit_invocation(Invocation(
-        "q-a", PROMPT, 2, cfg=cfg, qos=QosClass.BATCH,
-        simulate_read_bw=SLOW_BW))
+        "q-a", PROMPT, 2, cfg=cfg, qos=QosClass.BATCH))
     # wait until the restore owns a stream (RESTORING recorded), then a
     # beat more so reads are genuinely in flight
     deadline = time.time() + 10
@@ -150,17 +154,18 @@ def test_cancel_mid_restoring_aborts_stream_and_releases_ledger(qzoo):
     assert kinds["working_set"] == 0 and kinds["residual"] == 0
     node.memory.audit()
     # the function is not poisoned: the next invocation restores cleanly
+    slow_reads(None, _jif(catalog, "q-a"))
     r = node.invoke("q-a", PROMPT, max_new_tokens=3, cfg=cfg)
     assert r.cold
     np.testing.assert_array_equal(r.tokens, ref["q-a"])
     node.memory.audit()
 
 
-def test_cancel_after_ws_ready_is_noop_result_delivered(qzoo):
+def test_cancel_after_ws_ready_is_noop_result_delivered(qzoo, slow_reads):
     catalog, cfg, ref = qzoo
+    slow_reads(5e8, _jif(catalog, "q-b"))
     node = _node(catalog)
-    h = node.submit_invocation(Invocation(
-        "q-b", PROMPT, 3, cfg=cfg, simulate_read_bw=5e8))
+    h = node.submit_invocation(Invocation("q-b", PROMPT, 3, cfg=cfg))
     deadline = time.time() + 30
     while EVT_WS_READY not in _evts(h) and time.time() < deadline:
         time.sleep(0.002)
@@ -173,13 +178,13 @@ def test_cancel_after_ws_ready_is_noop_result_delivered(qzoo):
     node.memory.audit()
 
 
-def test_cancel_with_joiner_declines_and_joiner_survives(qzoo):
+def test_cancel_with_joiner_declines_and_joiner_survives(qzoo, slow_reads):
     """Cancelling the restore owner while a joiner rides the same stream
     must NOT abort it: the cancel is refused, both results deliver."""
     catalog, cfg, ref = qzoo
+    slow_reads(SLOW_BW, _jif(catalog, "q-a"))
     node = _node(catalog)
-    owner = node.submit_invocation(Invocation(
-        "q-a", PROMPT, 2, cfg=cfg, simulate_read_bw=SLOW_BW))
+    owner = node.submit_invocation(Invocation("q-a", PROMPT, 2, cfg=cfg))
     deadline = time.time() + 10
     while EVT_RESTORING not in _evts(owner) and time.time() < deadline:
         time.sleep(0.002)
@@ -211,11 +216,11 @@ def test_deadline_already_passed_rejects_at_submit(qzoo):
     assert node.stats["rejected_deadline"] == 1
 
 
-def test_deadline_expires_in_queue_typed_rejection(qzoo):
+def test_deadline_expires_in_queue_typed_rejection(qzoo, slow_reads):
     catalog, cfg, _ = qzoo
+    slow_reads(SLOW_BW, _jif(catalog, "q-a"))
     node = _node(catalog, max_workers=1)
-    jam = node.submit_invocation(Invocation(
-        "q-a", PROMPT, 2, cfg=cfg, simulate_read_bw=SLOW_BW))
+    jam = node.submit_invocation(Invocation("q-a", PROMPT, 2, cfg=cfg))
     doomed = node.submit_invocation(Invocation(
         "q-b", PROMPT, 2, cfg=cfg, deadline_s=deadline_in(0.02)))
     with pytest.raises(DeadlineExceeded):
@@ -226,12 +231,12 @@ def test_deadline_expires_in_queue_typed_rejection(qzoo):
     node.memory.audit()
 
 
-def test_admission_bounded_queue_overloaded(qzoo):
+def test_admission_bounded_queue_overloaded(qzoo, slow_reads):
     catalog, cfg, _ = qzoo
+    slow_reads(SLOW_BW, _jif(catalog, "q-a"))
     node = _node(catalog, max_workers=1,
                  admission=AdmissionController(max_queue_depth=1))
-    jam = node.submit_invocation(Invocation(
-        "q-a", PROMPT, 2, cfg=cfg, simulate_read_bw=SLOW_BW))
+    jam = node.submit_invocation(Invocation("q-a", PROMPT, 2, cfg=cfg))
     # worker busy; one queue slot. Fill it, then the next must be refused.
     deadline = time.time() + 10
     while EVT_RESTORING not in _evts(jam) and time.time() < deadline:
@@ -244,12 +249,12 @@ def test_admission_bounded_queue_overloaded(qzoo):
     ok.result(60)
 
 
-def test_admission_per_function_cap(qzoo):
+def test_admission_per_function_cap(qzoo, slow_reads):
     catalog, cfg, _ = qzoo
+    slow_reads(SLOW_BW, _jif(catalog, "q-a"))
     node = _node(catalog, max_workers=4,
                  admission=AdmissionController(default_function_cap=2))
-    h1 = node.submit_invocation(Invocation(
-        "q-a", PROMPT, 2, cfg=cfg, simulate_read_bw=SLOW_BW))
+    h1 = node.submit_invocation(Invocation("q-a", PROMPT, 2, cfg=cfg))
     h2 = node.submit_invocation(Invocation("q-a", PROMPT, 2, cfg=cfg))
     with pytest.raises(Overloaded):
         node.submit_invocation(Invocation("q-a", PROMPT, 2, cfg=cfg))
@@ -261,13 +266,13 @@ def test_admission_per_function_cap(qzoo):
     node.submit_invocation(Invocation("q-a", PROMPT, 2, cfg=cfg)).result(60)
 
 
-def test_qos_dispatch_order_latency_overtakes_batch(qzoo):
+def test_qos_dispatch_order_latency_overtakes_batch(qzoo, slow_reads):
     """With one worker jammed, a LATENCY invocation submitted AFTER a
     BATCH one must run first (QoS-ordered run queue, not FIFO)."""
     catalog, cfg, _ = qzoo
+    slow_reads(SLOW_BW, _jif(catalog, "q-a"))
     node = _node(catalog, max_workers=1)
-    jam = node.submit_invocation(Invocation(
-        "q-a", PROMPT, 2, cfg=cfg, simulate_read_bw=SLOW_BW))
+    jam = node.submit_invocation(Invocation("q-a", PROMPT, 2, cfg=cfg))
     batch = node.submit_invocation(Invocation(
         "q-b", PROMPT, 2, cfg=cfg, qos=QosClass.BATCH))
     lat = node.submit_invocation(Invocation(
@@ -313,8 +318,9 @@ def test_iosched_boost_priority_is_qos_weighted():
 
 
 # ------------------------------------------------------------------ router
-def test_router_latency_steal_from_backed_up_node(qzoo):
+def test_router_latency_steal_from_backed_up_node(qzoo, slow_reads):
     catalog, cfg, ref = qzoo
+    slow_reads(SLOW_BW, _jif(catalog, "q-b"))
     # one worker per node so STANDARD jams actually QUEUE (urgent_depth
     # counts queued non-batch work, not running occupancy)
     nodes = [NodeScheduler(registry=catalog.registry, name=f"node{i}",
@@ -329,8 +335,7 @@ def test_router_latency_steal_from_backed_up_node(qzoo):
     other = [n for n in nodes if n is not sticky][0]
     # STANDARD jams count as urgent backlog (parked BATCH work would not:
     # the QoS queue dispatches a LATENCY invocation straight past it)
-    jams = [sticky.submit_invocation(Invocation(
-        "q-b", PROMPT, 2, cfg=cfg, simulate_read_bw=SLOW_BW))
+    jams = [sticky.submit_invocation(Invocation("q-b", PROMPT, 2, cfg=cfg))
         for _ in range(3)]
     deadline = time.time() + 10
     while sticky.load().urgent_depth < 2 and time.time() < deadline:
@@ -354,13 +359,13 @@ def test_router_latency_steal_from_backed_up_node(qzoo):
     router.close()
 
 
-def test_router_close_idempotent_and_drains_queue(qzoo):
+def test_router_close_idempotent_and_drains_queue(qzoo, slow_reads):
     catalog, cfg, _ = qzoo
+    slow_reads(SLOW_BW, _jif(catalog, "q-a"))
     nodes = [NodeScheduler(registry=catalog.registry, name="n0",
                            max_workers=1, keepalive=FixedTTLPolicy(3600.0))]
     router = ClusterRouter(catalog, nodes)
-    jam = router.submit_invocation(Invocation(
-        "q-a", PROMPT, 2, cfg=cfg, simulate_read_bw=SLOW_BW))
+    jam = router.submit_invocation(Invocation("q-a", PROMPT, 2, cfg=cfg))
     queued = [router.submit_invocation(Invocation(
         "q-b", PROMPT, 2, cfg=cfg, qos=QosClass.BATCH)) for _ in range(3)]
     router.close()
@@ -377,12 +382,15 @@ def test_router_close_idempotent_and_drains_queue(qzoo):
 
 
 # ------------------------------------------------------------ property test
-def test_random_cancel_deadline_interleavings_never_leak_ledger(qzoo):
+def test_random_cancel_deadline_interleavings_never_leak_ledger(qzoo, slow_reads):
     """Seeded chaos: random QoS classes, deadlines, and cancel delays over
-    both functions.  Every handle must settle with a typed outcome, the
-    ledger invariant must hold throughout, and once everything is evicted
-    the working-set/residual columns must return to zero bytes."""
+    both functions, one restoring slowly and one fast.  Every handle must
+    settle with a typed outcome, the ledger invariant must hold throughout,
+    and once everything is evicted the working-set/residual columns must
+    return to zero bytes."""
     catalog, cfg, ref = qzoo
+    slow_reads(SLOW_BW, _jif(catalog, "q-a"))
+    slow_reads(5e8, _jif(catalog, "q-b"))
     rng = np.random.default_rng(1234)
     node = _node(catalog, max_workers=4,
                  admission=AdmissionController(max_queue_depth=16))
@@ -394,11 +402,9 @@ def test_random_cancel_deadline_interleavings_never_leak_ledger(qzoo):
             int(rng.integers(3))]
         dl = deadline_in(float(rng.uniform(0.005, 3.0))) \
             if rng.random() < 0.3 else None
-        bw = SLOW_BW if rng.random() < 0.5 else 5e8
         try:
             h = node.submit_invocation(Invocation(
-                fname, PROMPT, 2, cfg=cfg, qos=qos, deadline_s=dl,
-                simulate_read_bw=bw))
+                fname, PROMPT, 2, cfg=cfg, qos=qos, deadline_s=dl))
         except (Overloaded, DeadlineExceeded):
             continue
         handles.append(h)

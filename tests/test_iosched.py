@@ -147,15 +147,3 @@ def test_boost_entry_expires_with_its_job():
     # a's tail finished
     assert order.index("a1") < order.index("b1")
     assert order.index("b0") < order.index("a3")
-
-
-def test_inline_stream_drains_on_caller_thread():
-    sched = PrefetchIOScheduler("t")
-    done = []
-    stream = sched.open_stream("sync", inline=True)
-    for i in range(3):
-        stream.submit(f"t{i}", [_op(500)], (lambda n=i: done.append(n)))
-    stream.seal()
-    sched.drain_inline(stream)
-    assert stream.done and done == [0, 1, 2]
-    assert sched.snapshot_stats()["bytes_read"] == 1500

@@ -206,7 +206,7 @@ def test_base_image_from_jif_matches_from_state(tmp_path):
 
 
 # ------------------------------------------------------- two-phase completion
-def test_working_set_event_fires_before_residual(tmp_path):
+def test_working_set_event_fires_before_residual(tmp_path, slow_reads):
     state = rng_state(3, scale=8)
     names = [n for n, _ in flatten_state(state)[0]]
     ws = names[:3]
@@ -214,7 +214,8 @@ def test_working_set_event_fires_before_residual(tmp_path):
     snapshot(state, path, access_order=names, working_set=ws, page_size=PAGE)
 
     at_ws = {}
-    restorer = SpiceRestorer(simulate_read_bw=5e7)
+    slow_reads(5e7)
+    restorer = SpiceRestorer()
     _, meta, handles, stats = restorer.restore(
         path, wait=False,
         on_working_set=lambda: at_ws.update(complete=stats.complete),
@@ -234,14 +235,15 @@ def test_working_set_event_fires_before_residual(tmp_path):
         )
 
 
-def test_residual_demand_boost_still_works(tmp_path):
+def test_residual_demand_boost_still_works(tmp_path, slow_reads):
     """Waiting on a residual tensor after ws completion demand-boosts it
     ahead of the background stream."""
     state = rng_state(4, scale=8)
     names = [n for n, _ in flatten_state(state)[0]]
     path = str(tmp_path / "f.jif")
     snapshot(state, path, access_order=names, working_set=names[:2], page_size=PAGE)
-    restorer = SpiceRestorer(simulate_read_bw=3e7)
+    slow_reads(3e7)
+    restorer = SpiceRestorer()
     _, _, handles, stats = restorer.restore(path, wait=False)
     assert stats.wait_working_set(20)
     tail = names[-1]

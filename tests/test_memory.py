@@ -180,7 +180,7 @@ def test_pool_foreign_release_is_dropped():
 
 def test_pool_gc_sweep_reclaims_leaked_charges():
     """A caller that drops an acquired buffer without releasing it (e.g. a
-    non-pipelined restore whose state tree dies) must not pin the charge."""
+    restore whose state tree dies) must not pin the charge."""
     pool = BufferPool(capacity_bytes=64 << 10)
     buf = pool.acquire(32 << 10)
     assert pool.held_bytes == 32 << 10
@@ -378,7 +378,7 @@ def test_concurrent_restores_over_budget_complete_via_reclaim(tmp_path):
         node.publish(fname, cfg, params, str(tmp_path), formats=("jif",),
                      extra_state=extra)
     # compile-cache warmup, then a clean slate
-    node.invoke(fnames[0], PROMPT, max_new_tokens=2, mode="spice_sync", cfg=cfg)
+    node.invoke(fnames[0], PROMPT, max_new_tokens=2, cfg=cfg)
     node.evict()
     node.scheduler.drain_residual()
 
@@ -391,7 +391,7 @@ def test_concurrent_restores_over_budget_complete_via_reclaim(tmp_path):
     node.scheduler.memory_budget = budget
 
     futures = [
-        node.submit(f, PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+        node.submit(f, PROMPT, max_new_tokens=2, cfg=cfg)
         for f in fnames
     ]
     peak = 0

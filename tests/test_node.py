@@ -30,7 +30,7 @@ def node_with_zoo(tmp_path_factory):
         node.publish(fname, cfg, params, str(d), warm_ttl_s=0.0,
                      formats=("jif", "monolith"))
     # compile-cache warmup (shared across functions of one arch)
-    node.invoke(FNAMES[0], PROMPT, max_new_tokens=3, mode="spice_sync", cfg=cfg)
+    node.invoke(FNAMES[0], PROMPT, max_new_tokens=3, cfg=cfg)
     return node, cfg
 
 
@@ -40,13 +40,13 @@ def test_concurrent_cold_invokes_match_warm_reference(node_with_zoo):
     ref = {}
     for fname in FNAMES:
         node.evict()
-        r = node.invoke(fname, PROMPT, max_new_tokens=4, mode="spice_sync", cfg=cfg)
+        r = node.invoke(fname, PROMPT, max_new_tokens=4, cfg=cfg)
         ref[fname] = r.tokens
     node.evict()
 
     before = node.iosched.snapshot_stats()
     futures = [
-        node.submit(fname, PROMPT, max_new_tokens=4, mode="spice", cfg=cfg)
+        node.submit(fname, PROMPT, max_new_tokens=4, cfg=cfg)
         for fname in FNAMES
     ]
     results = {f.result().function: f.result() for f in futures}
@@ -66,7 +66,7 @@ def test_concurrent_same_function_joins_inflight_restore(node_with_zoo):
     node, cfg = node_with_zoo
     node.evict()
     futures = [
-        node.submit(FNAMES[0], PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+        node.submit(FNAMES[0], PROMPT, max_new_tokens=3, cfg=cfg)
         for _ in range(4)
     ]
     results = [f.result() for f in futures]
@@ -78,15 +78,15 @@ def test_concurrent_same_function_joins_inflight_restore(node_with_zoo):
     assert sum(1 for r in results if r.joined) == len(results) - 1
 
 
-def test_contended_restores_issue_demand_boosts(node_with_zoo):
-    """With several functions restoring through one arbiter at simulated
-    NVMe bandwidth, execution demand must overtake background prefetch."""
+def test_contended_restores_issue_demand_boosts(node_with_zoo, slow_reads):
+    """With several functions restoring through one arbiter at NVMe-like
+    paced bandwidth, execution demand must overtake background prefetch."""
     node, cfg = node_with_zoo
     node.evict()
+    slow_reads(1e9)
     before = node.iosched.snapshot_stats()["demand_boosts"]
     futures = [
-        node.submit(fname, PROMPT, max_new_tokens=3, mode="spice", cfg=cfg,
-                    simulate_read_bw=1e9)
+        node.submit(fname, PROMPT, max_new_tokens=3, cfg=cfg)
         for fname in FNAMES[:3]
     ]
     for f in futures:
@@ -103,13 +103,13 @@ def test_warm_ttl_expiry_takes_cold_path(tmp_path):
     node = ServerlessNode()
     node.publish("ttl-fn", cfg, params, str(tmp_path), warm_ttl_s=0.4,
                  formats=("jif",))
-    r1 = node.invoke("ttl-fn", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
-    r2 = node.invoke("ttl-fn", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+    r1 = node.invoke("ttl-fn", PROMPT, max_new_tokens=3, cfg=cfg)
+    r2 = node.invoke("ttl-fn", PROMPT, max_new_tokens=3, cfg=cfg)
     assert r1.cold and not r2.cold  # within TTL: warm
     inst = node.scheduler.instance("ttl-fn")
     assert inst.state is InstanceState.WARM
     time.sleep(0.5)
-    r3 = node.invoke("ttl-fn", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+    r3 = node.invoke("ttl-fn", PROMPT, max_new_tokens=3, cfg=cfg)
     assert r3.cold  # expired: evicted, cold path again
     assert node.scheduler.stats["ttl_evictions"] >= 1
     assert inst.counters["ttl_evictions"] >= 1
@@ -127,7 +127,7 @@ def test_background_reaper_evicts_idle_expired_instance(tmp_path):
     try:
         node.publish("reap-fn", cfg, params, str(tmp_path), warm_ttl_s=0.3,
                      formats=("jif",))
-        r = node.invoke("reap-fn", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+        r = node.invoke("reap-fn", PROMPT, max_new_tokens=2, cfg=cfg)
         assert r.cold
         node.scheduler.drain_residual()
         inst = node.scheduler.instance("reap-fn")
@@ -158,7 +158,7 @@ def test_lru_eviction_under_memory_budget(tmp_path):
         params = lm.init_params(cfg, jax.random.PRNGKey(20 + i), jnp.float32)
         node.publish(fname, cfg, params, str(tmp_path), formats=("jif",))
 
-    r = node.invoke("lru-a", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    r = node.invoke("lru-a", PROMPT, max_new_tokens=2, cfg=cfg)
     assert r.cold
     inst_a = node.scheduler.instance("lru-a")
     assert inst_a.state is InstanceState.WARM and inst_a.memory_bytes > 0
@@ -166,16 +166,16 @@ def test_lru_eviction_under_memory_budget(tmp_path):
     # ladder trims the (expendable) free list first, so only a budget this
     # tight forces the warm-LRU rung
     node.scheduler.memory_budget = int(1.5 * inst_a.memory_bytes)
-    node.invoke("lru-b", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    node.invoke("lru-b", PROMPT, max_new_tokens=2, cfg=cfg)
     assert node.scheduler.instance("lru-a").state is InstanceState.EVICTED
     assert node.scheduler.instance("lru-b").state is InstanceState.WARM
-    node.invoke("lru-c", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    node.invoke("lru-c", PROMPT, max_new_tokens=2, cfg=cfg)
     assert node.scheduler.instance("lru-b").state is InstanceState.EVICTED
     assert node.scheduler.instance("lru-c").state is InstanceState.WARM
     assert node.scheduler.stats["lru_evictions"] >= 2
 
 
-def test_warm_at_working_set_promotion(tmp_path):
+def test_warm_at_working_set_promotion(tmp_path, slow_reads):
     """With residual state behind the ws boundary, the owner promotes at
     working-set completion (WARMING) instead of waiting for the full image;
     the residual finalizes WARM in the background."""
@@ -185,8 +185,8 @@ def test_warm_at_working_set_promotion(tmp_path):
     extra = {"opt": np.ones((1 << 20,), np.float32)}  # 4 MB residual tail
     node.publish("ws-fn", cfg, params, str(tmp_path), warm_ttl_s=60,
                  formats=("jif",), extra_state=extra)
-    r1 = node.invoke("ws-fn", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg,
-                     simulate_read_bw=5e8)
+    slow_reads(5e8)
+    r1 = node.invoke("ws-fn", PROMPT, max_new_tokens=3, cfg=cfg)
     assert r1.cold
     assert r1.stats["ws_ready"]
     assert r1.stats["residual_tensors"] > 0
@@ -221,7 +221,7 @@ def test_record_access_then_relayout(tmp_path):
     node = ServerlessNode()
     node.publish("rl-fn", cfg, params, str(tmp_path), warm_ttl_s=60,
                  formats=("jif",))
-    r1 = node.invoke("rl-fn", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+    r1 = node.invoke("rl-fn", PROMPT, max_new_tokens=3, cfg=cfg)
     assert r1.cold
 
     order = node.record_access("rl-fn", PROMPT, max_new_tokens=2, cfg=cfg)
@@ -238,7 +238,7 @@ def test_record_access_then_relayout(tmp_path):
         assert r.meta.get("relayout") is True
 
     node.evict()
-    r2 = node.invoke("rl-fn", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+    r2 = node.invoke("rl-fn", PROMPT, max_new_tokens=3, cfg=cfg)
     assert r2.cold
     np.testing.assert_array_equal(r1.tokens, r2.tokens)
 
@@ -253,7 +253,7 @@ def test_residual_evict_then_cheap_rerestore(tmp_path):
     extra = {"opt": np.ones((1 << 20,), np.float32)}  # 4 MB residual tail
     node.publish("rr-fn", cfg, params, str(tmp_path), warm_ttl_s=60,
                  formats=("jif",), extra_state=extra)
-    r1 = node.invoke("rr-fn", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+    r1 = node.invoke("rr-fn", PROMPT, max_new_tokens=3, cfg=cfg)
     assert r1.cold
     assert node.scheduler.drain_residual()
     inst = node.scheduler.instance("rr-fn")
@@ -269,7 +269,7 @@ def test_residual_evict_then_cheap_rerestore(tmp_path):
     assert node.scheduler.stats["residual_evictions"] == 1
     node.memory.audit()  # pinned ws still charged, residual uncharged
 
-    r2 = node.invoke("rr-fn", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+    r2 = node.invoke("rr-fn", PROMPT, max_new_tokens=3, cfg=cfg)
     assert r2.cold  # a restore, but a cheap one
     assert node.scheduler.drain_residual()
     d2 = inst.restore_stats.as_dict()
@@ -282,7 +282,7 @@ def test_residual_evict_then_cheap_rerestore(tmp_path):
     node.memory.audit()
 
 
-def test_manual_evict_waits_for_warming(tmp_path):
+def test_manual_evict_waits_for_warming(tmp_path, slow_reads):
     """Regression: evict() during the WARMING window used to no-op (the
     residual stream is unevictable mid-flight), so the next invocation
     silently routed warm instead of cold."""
@@ -294,15 +294,16 @@ def test_manual_evict_waits_for_warming(tmp_path):
                  formats=("jif",), extra_state=extra)
     # warm the compile cache so the invoke returns DURING the residual
     # stream (the race window)
-    node.invoke("ev-fn", PROMPT, max_new_tokens=2, mode="spice_sync", cfg=cfg)
+    node.invoke("ev-fn", PROMPT, max_new_tokens=2, cfg=cfg)
     node.evict()
-    r1 = node.invoke("ev-fn", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg,
-                     simulate_read_bw=5e8)
+    slow_reads(5e8)
+    r1 = node.invoke("ev-fn", PROMPT, max_new_tokens=2, cfg=cfg)
     assert r1.cold
     node.evict()  # must wait out WARMING, then actually evict
     inst = node.scheduler.instance("ev-fn")
     assert inst.state is InstanceState.EVICTED
-    r2 = node.invoke("ev-fn", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    slow_reads(None)
+    r2 = node.invoke("ev-fn", PROMPT, max_new_tokens=2, cfg=cfg)
     assert r2.cold
     np.testing.assert_array_equal(r1.tokens, r2.tokens)
 
@@ -319,8 +320,8 @@ def test_reclaim_ladder_order(tmp_path):
         params = lm.init_params(cfg, jax.random.PRNGKey(60 + i), jnp.float32)
         node.publish(fname, cfg, params, str(tmp_path), warm_ttl_s=3600,
                      formats=("jif",), extra_state=extra)
-    node.invoke("lad-a", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
-    node.invoke("lad-b", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+    node.invoke("lad-a", PROMPT, max_new_tokens=2, cfg=cfg)
+    node.invoke("lad-b", PROMPT, max_new_tokens=2, cfg=cfg)
     assert node.scheduler.drain_residual()
     img = BaseImage.from_state("lad-img", {"x": np.ones((1 << 18,), np.float32)})
     node.node_cache.put(img)  # 1 MB cached image
@@ -362,7 +363,7 @@ def test_instance_state_machine_transitions():
     inst = FunctionInstance(spec, cfg=None)
     assert inst.state is InstanceState.COLD
     with inst.cond:
-        gen = inst.begin_restore("spice")
+        gen = inst.begin_restore()
         assert inst.state is InstanceState.RESTORING and gen == 1
         inst.publish_restore({"x": 1}, None, None)
         inst.promote_warm({"x": np.zeros(64)}, ttl_s=10.0, now=time.time())
@@ -371,7 +372,7 @@ def test_instance_state_machine_transitions():
         assert inst.evict("manual")
         assert inst.state is InstanceState.EVICTED
         # next restore bumps the generation
-        assert inst.begin_restore("spice") == 2
+        assert inst.begin_restore() == 2
         inst.publish_restore({"x": 1}, None, None)
         inst.promote_warm({"x": 1}, ttl_s=0.0, now=time.time())  # no keep-alive
         assert inst.state is InstanceState.COLD and inst.tree is None

@@ -31,7 +31,7 @@ def catalog_with_fns(tmp_path_factory):
         catalog.publish(fname, cfg, params, str(d), warm_ttl_s=0.0,
                         formats=("jif",))
     node = NodeScheduler(registry=catalog.registry)  # compile-cache warmup
-    node.invoke("pw-a", PROMPT, max_new_tokens=2, mode="spice_sync", cfg=cfg)
+    node.invoke("pw-a", PROMPT, max_new_tokens=2, cfg=cfg)
     return catalog, cfg
 
 
@@ -170,8 +170,7 @@ def test_speculative_restore_promotes_warm_without_generation(catalog_with_fns):
     router = ClusterRouter(catalog, [node], prewarm=engine)
     try:
         # one real invocation: sticky placement + the instance's cfg
-        r0 = router.invoke("pw-a", PROMPT, max_new_tokens=2, mode="spice",
-                           cfg=cfg)
+        r0 = router.invoke("pw-a", PROMPT, max_new_tokens=2, cfg=cfg)
         assert r0.cold
         node.evict("pw-a")
         _warm_history(engine, "pw-a")
@@ -183,8 +182,7 @@ def test_speculative_restore_promotes_warm_without_generation(catalog_with_fns):
         assert node.stats["cold_starts"] == 1  # only the priming call
         assert engine.stats["speculative_ok"] == 1
         # the real arrival the engine predicted: a plain warm hit
-        r1 = router.invoke("pw-a", PROMPT, max_new_tokens=2, mode="spice",
-                           cfg=cfg)
+        r1 = router.invoke("pw-a", PROMPT, max_new_tokens=2, cfg=cfg)
         assert not r1.cold
         np.testing.assert_array_equal(r0.tokens, r1.tokens)
     finally:
@@ -201,7 +199,7 @@ def test_engine_suppresses_resident_and_unknown_functions(catalog_with_fns):
     )
     router = ClusterRouter(catalog, [node], prewarm=engine)
     try:
-        router.invoke("pw-a", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+        router.invoke("pw-a", PROMPT, max_new_tokens=2, cfg=cfg)
         _warm_history(engine, "pw-a")      # warm: must not re-restore
         _warm_history(engine, "ghost-fn")  # tracked but never published
         assert engine.tick() == 0
@@ -211,13 +209,13 @@ def test_engine_suppresses_resident_and_unknown_functions(catalog_with_fns):
         router.close()
 
 
-def test_real_invocation_joins_inflight_speculative_restore(catalog_with_fns):
+def test_real_invocation_joins_inflight_speculative_restore(catalog_with_fns,
+                                                           slow_reads):
     """A real arrival mid-speculation rides the SAME restore: exactly one
     restore owner (the speculation), the real result marked joined, its
     timeline showing the RESTORING ride."""
     catalog, cfg = catalog_with_fns
-    engine = PrewarmEngine(horizon_s=5.0, interval_s=None, min_observations=2,
-                           simulate_read_bw=4e6)  # slow restore: ~1 s window
+    engine = PrewarmEngine(horizon_s=5.0, interval_s=None, min_observations=2)
     node = NodeScheduler(
         registry=catalog.registry,
         keepalive=PrewarmPolicy(engine.tracker, default_ttl_s=30.0,
@@ -225,8 +223,10 @@ def test_real_invocation_joins_inflight_speculative_restore(catalog_with_fns):
     )
     router = ClusterRouter(catalog, [node], prewarm=engine)
     try:
-        router.invoke("pw-b", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+        router.invoke("pw-b", PROMPT, max_new_tokens=2, cfg=cfg)
         node.evict("pw-b")
+        # slow restore: ~1 s window
+        slow_reads(4e6, catalog.registry.get("pw-b").jif_path)
         _warm_history(engine, "pw-b")
         assert engine.tick() == 1
         inst = node.instance("pw-b")
@@ -236,7 +236,7 @@ def test_real_invocation_joins_inflight_speculative_restore(catalog_with_fns):
             time.sleep(0.002)
         assert inst.state is InstanceState.RESTORING
         h = router.submit_invocation(Invocation(
-            function="pw-b", prompt=PROMPT, max_new_tokens=2, mode="spice",
+            function="pw-b", prompt=PROMPT, max_new_tokens=2,
             cfg=cfg, qos=QosClass.LATENCY,
         ))
         r = h.result(60.0)
@@ -258,9 +258,9 @@ def test_redundant_speculation_against_warm_instance_is_a_noop(catalog_with_fns)
     )
     router = ClusterRouter(catalog, [node])
     try:
-        router.invoke("pw-a", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+        router.invoke("pw-a", PROMPT, max_new_tokens=2, cfg=cfg)
         h = router.submit_invocation(Invocation(
-            function="pw-a", prompt=None, max_new_tokens=0, mode="spice",
+            function="pw-a", prompt=None, max_new_tokens=0,
             qos=QosClass.BATCH, prewarm=True,
         ))
         r = h.result(30.0)
@@ -286,8 +286,8 @@ def test_reaper_honors_adaptive_ttls(catalog_with_fns):
             tracker.record("pw-b", now=t)
         for t in (now - 20.0, now - 10.0, now):  # pw-c: ~12.5 s window
             tracker.record("pw-c", now=t)
-        router.invoke("pw-b", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
-        router.invoke("pw-c", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+        router.invoke("pw-b", PROMPT, max_new_tokens=2, cfg=cfg)
+        router.invoke("pw-c", PROMPT, max_new_tokens=2, cfg=cfg)
         assert node.instance("pw-b").state is InstanceState.WARM
         time.sleep(0.4)  # past pw-b's adaptive TTL, well inside pw-c's
         assert node.reap_expired() == 1
@@ -310,7 +310,7 @@ def test_mispredicted_speculation_yields_to_reclaim_ladder(catalog_with_fns):
     )
     router = ClusterRouter(catalog, [node], prewarm=engine)
     try:
-        router.invoke("pw-c", PROMPT, max_new_tokens=2, mode="spice", cfg=cfg)
+        router.invoke("pw-c", PROMPT, max_new_tokens=2, cfg=cfg)
         node.evict("pw-c")
         _warm_history(engine, "pw-c")
         assert engine.tick() == 1

@@ -492,15 +492,13 @@ def test_install_policy_end_to_end(policy_zoo, install, tmp_path):
     node = ServerlessNode(install=install)
     try:
         _publish(node, tmp_path, cfg, params)
-        r = node.invoke("pol-fn", PROMPT, max_new_tokens=3, mode="spice",
-                        cfg=cfg)
+        r = node.invoke("pol-fn", PROMPT, max_new_tokens=3, cfg=cfg)
         assert r.cold
         assert node.scheduler.drain_residual()
         node.memory.audit()
         # every policy generates the same tokens
         node.evict()
-        r2 = node.invoke("pol-fn", PROMPT, max_new_tokens=3,
-                         mode="spice_sync", cfg=cfg)
+        r2 = node.invoke("pol-fn", PROMPT, max_new_tokens=3, cfg=cfg)
         np.testing.assert_array_equal(r.tokens, r2.tokens)
     finally:
         node.close()
@@ -551,8 +549,7 @@ def test_residual_evict_rerestore_keeps_device_base(policy_zoo, tmp_path):
     try:
         extra = {"opt": np.ones((1 << 20,), np.float32)}  # 4 MB residual
         _publish(node, tmp_path, cfg, params, extra=extra)
-        r1 = node.invoke("pol-fn", PROMPT, max_new_tokens=3, mode="spice",
-                         cfg=cfg)
+        r1 = node.invoke("pol-fn", PROMPT, max_new_tokens=3, cfg=cfg)
         assert r1.cold
         assert node.scheduler.drain_residual()
         inst = node.scheduler.instance("pol-fn")
@@ -567,8 +564,7 @@ def test_residual_evict_rerestore_keeps_device_base(policy_zoo, tmp_path):
         node.memory.audit()
         up_before = node.scheduler.upload_stream.snapshot_stats()
 
-        r2 = node.invoke("pol-fn", PROMPT, max_new_tokens=3, mode="spice",
-                         cfg=cfg)
+        r2 = node.invoke("pol-fn", PROMPT, max_new_tokens=3, cfg=cfg)
         assert r2.cold
         assert node.scheduler.drain_residual()
         d2 = inst.restore_stats.as_dict()
@@ -706,7 +702,7 @@ def test_shared_base_arrays_outlive_instances_and_the_cache_entry(policy_zoo, tm
                          formats=("jif",), warm_ttl_s=60,
                          extra_state={"opt": np.ones((1 << 16,), np.float32)})
         images = node.scheduler.device_images
-        r = node.invoke("pol-fn", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+        r = node.invoke("pol-fn", PROMPT, max_new_tokens=3, cfg=cfg)
         assert r.cold
         assert node.scheduler.drain_residual()
         inst = node.scheduler.instance("pol-fn")
@@ -717,7 +713,7 @@ def test_shared_base_arrays_outlive_instances_and_the_cache_entry(policy_zoo, tm
                   if any(a is dev for dev in entries)]
         assert len(shared) == stats.shared_tensors == len(entries)
         host = [np.asarray(a) for a in shared]
-        warm = node.invoke("pol-fn", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+        warm = node.invoke("pol-fn", PROMPT, max_new_tokens=3, cfg=cfg)
         assert not warm.cold
         np.testing.assert_array_equal(r.tokens, warm.tokens)
 
@@ -726,19 +722,19 @@ def test_shared_base_arrays_outlive_instances_and_the_cache_entry(policy_zoo, tm
         assert images.reclaim(1 << 40) > 0 and images.resident_entries() == 0
         node.memory.audit()
         assert all(not a.is_deleted() for a in shared)
-        again = node.invoke("pol-fn", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+        again = node.invoke("pol-fn", PROMPT, max_new_tokens=3, cfg=cfg)
         assert not again.cold
         np.testing.assert_array_equal(r.tokens, again.tokens)
 
         # a second fine-tune's restore builds the entries once more, then a
         # third restore of it hits them
-        r2 = node.invoke("pol-fn2", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+        r2 = node.invoke("pol-fn2", PROMPT, max_new_tokens=3, cfg=cfg)
         assert r2.cold
         assert node.scheduler.drain_residual()
         built = images.snapshot_stats()
         assert built["misses"] == mid["misses"] + stats.shared_tensors
         node.evict("pol-fn2")
-        r3 = node.invoke("pol-fn2", PROMPT, max_new_tokens=3, mode="spice", cfg=cfg)
+        r3 = node.invoke("pol-fn2", PROMPT, max_new_tokens=3, cfg=cfg)
         assert r3.cold
         assert node.scheduler.drain_residual()
         after = images.snapshot_stats()
